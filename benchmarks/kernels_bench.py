@@ -1,5 +1,5 @@
-"""Kernel micro-benchmarks (interpret mode on CPU — correctness-path cost;
-TPU is the target for absolute numbers)."""
+"""Kernel micro-benchmarks.  Off a TPU the kernels run in Pallas interpret
+mode (correctness-path cost only); each row says which mode it timed."""
 from __future__ import annotations
 
 import time
@@ -8,6 +8,7 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.checksum.ops import fingerprint
 from repro.kernels.ssd_scan.ops import ssd_chunked_pallas
 from repro.kernels.swa_attention.ops import swa_attention
@@ -25,16 +26,17 @@ def _time(fn, n=3) -> float:
 def run() -> List[Dict]:
     key = jax.random.PRNGKey(0)
     rows = []
+    mode = f"interpret={default_interpret()}"
 
     x = jax.random.normal(key, (1 << 20,), jnp.float32)
     t = _time(lambda: fingerprint(x))
     rows.append({"name": "kernel/checksum/4MB", "us_per_call": t * 1e6,
-                 "derived": f"GBps={x.nbytes/t/1e9:.2f}"})
+                 "derived": f"GBps={x.nbytes/t/1e9:.2f} {mode}"})
 
     q = jax.random.normal(key, (1, 512, 4, 64), jnp.float32)
     t = _time(lambda: swa_attention(q, q, q, window=128))
     rows.append({"name": "kernel/swa_attn/512x4x64_w128",
-                 "us_per_call": t * 1e6, "derived": "interpret=True"})
+                 "us_per_call": t * 1e6, "derived": mode})
 
     xs = jax.random.normal(key, (1, 256, 4, 32), jnp.float32)
     dt = jax.nn.softplus(jax.random.normal(key, (1, 256, 4)))

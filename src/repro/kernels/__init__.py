@@ -12,6 +12,18 @@
 
 Each kernel directory has: the ``pl.pallas_call`` kernel with explicit
 BlockSpec VMEM tiling, ``ops.py`` (jit'd public wrapper), ``ref.py``
-(pure-jnp oracle).  Kernels are validated in interpret mode on CPU; TPU is
-the target.
+(pure-jnp oracle).  ``interpret`` defaults to ``default_interpret()``:
+compiled by Mosaic on a TPU, interpreted anywhere else (the CPU tests).
+``checksum``, ``swa_attention`` and ``fused_ce`` compile for v5e
+(``tests/test_tpu_compile.py``).
+
+``ssd_scan`` is interpret-only: the v5e compiler refuses it (``cumsum``
+has no Mosaic lowering, and the kernel builds 3-D ``(Q, Q, H)``
+intermediates).  Wiring it in or deleting it is open.
 """
+import jax
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode exactly when JAX's backend is not a TPU."""
+    return jax.default_backend() != "tpu"

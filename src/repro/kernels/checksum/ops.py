@@ -1,6 +1,8 @@
 """Public fingerprint op: arbitrary-array content digest via the Pallas kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +29,7 @@ def _as_words(arr: jax.Array) -> jax.Array:
     return a.reshape(-1, LANES)
 
 
-def fingerprint(arr: jax.Array, interpret: bool = True) -> jax.Array:
+def fingerprint(arr: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     """128-bit content digest of an array, computed on-device.
 
     Equal contents (same dtype/shape) always produce equal digests;

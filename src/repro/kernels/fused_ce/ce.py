@@ -14,11 +14,14 @@ f32 running stats persist in VMEM scratch across the vocab dimension
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 BT = 128
 BV = 512
@@ -65,7 +68,7 @@ def _kernel(h_ref, w_ref, lab_ref, lse_ref, pick_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_ce_stats(hidden: jax.Array, head: jax.Array, labels: jax.Array,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """hidden (T, d) x head (d, V), labels (T,) -> (lse (T,1), pick (T,1)).
 
     T must be a multiple of BT; V is padded internally to BV multiples.
@@ -102,5 +105,5 @@ def fused_ce_stats(hidden: jax.Array, head: jax.Array, labels: jax.Array,
             pltpu.VMEM((BT, 1), jnp.float32),
             pltpu.VMEM((BT, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(hidden, head, lab2)

@@ -1,7 +1,7 @@
 """Public fused-CE op: padding + masking around the kernel."""
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +10,7 @@ from repro.kernels.fused_ce.ce import BT, fused_ce_stats
 
 
 def fused_cross_entropy(hidden: jax.Array, head: jax.Array,
-                        labels: jax.Array, interpret: bool = True
+                        labels: jax.Array, interpret: Optional[bool] = None
                         ) -> Tuple[jax.Array, jax.Array]:
     """Token-level CE without materializing logits.
 

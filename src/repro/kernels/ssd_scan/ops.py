@@ -11,8 +11,7 @@ from repro.kernels.ssd_scan.ssd import ssd_intra_chunk
 
 def ssd_chunked_pallas(x: jax.Array, dt: jax.Array, a: jax.Array,
                        b: jax.Array, c: jax.Array, chunk: int,
-                       initial_state: Optional[jax.Array] = None,
-                       interpret: bool = True
+                       initial_state: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array]:
     """Drop-in replacement for ``models.ssm.ssd_chunked`` backed by the
     Pallas kernel.  Shapes as there: x (B,L,H,P), dt (B,L,H), a (H,),
@@ -33,7 +32,7 @@ def ssd_chunked_pallas(x: jax.Array, dt: jax.Array, a: jax.Array,
     cc = c.reshape(bs * nc, chunk, n)
 
     y_intra, states, cum = ssd_intra_chunk(
-        xc, dtc, a.astype(jnp.float32), bc_, cc, interpret=interpret)
+        xc, dtc, a.astype(jnp.float32), bc_, cc)
     y_intra = y_intra.reshape(bs, nc, chunk, h, p)
     states = states.reshape(bs, nc, h, p, n)
     cum = cum.reshape(bs, nc, chunk, h)

@@ -14,8 +14,6 @@ N = state_dim — Q x N and Q x Q matmuls are MXU-shaped.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -54,11 +52,13 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     cum_ref[0] = cum
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def ssd_intra_chunk(x: jax.Array, dt: jax.Array, a: jax.Array,
-                    b: jax.Array, c: jax.Array,
-                    interpret: bool = True):
+                    b: jax.Array, c: jax.Array):
     """x: (BC, Q, H, P), dt: (BC, Q, H), a: (H,), b/c: (BC, Q, N).
+
+    Interpret-only on every backend: the v5e compiler refuses this kernel
+    (no Mosaic lowering of ``cumsum``; 3-D ``(Q, Q, H)`` intermediates).
 
     Returns (y_intra (BC,Q,H,P) f32, states (BC,H,P,N) f32, cum (BC,Q,H) f32).
     """
@@ -84,5 +84,5 @@ def ssd_intra_chunk(x: jax.Array, dt: jax.Array, a: jax.Array,
             pl.BlockSpec((1, h, p, n), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((1, q, h), lambda i: (i, 0, 0)),
         ),
-        interpret=interpret,
+        interpret=True,
     )(x, dt, a, b, c)

@@ -1,6 +1,8 @@
 """Public sliding-window attention op: padding + head layout around the kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -8,7 +10,7 @@ from repro.kernels.swa_attention.swa import BKV, BQ, swa_flash
 
 
 def swa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  window: int = 0, interpret: bool = True) -> jax.Array:
+                  window: int = 0, interpret: Optional[bool] = None) -> jax.Array:
     """Causal (optionally sliding-window) attention.
 
     q, k, v: (B, S, H, D) — kv heads already repeated to H (GQA handled by

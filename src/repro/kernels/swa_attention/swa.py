@@ -16,12 +16,15 @@ off-diagonal blocks are skipped via ``pl.when``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 BQ = 128
 BKV = 128
@@ -87,7 +90,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit,
                    static_argnames=("window", "seq_kv", "interpret"))
 def swa_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
-              seq_kv: int, interpret: bool = True) -> jax.Array:
+              seq_kv: int, interpret: Optional[bool] = None) -> jax.Array:
     """q: (BH, Sq, D), k/v: (BH, Skv, D), padded to block multiples.
 
     Returns (BH, Sq, D).  ``seq_kv`` is the unpadded kv length (masking).
@@ -118,5 +121,5 @@ def swa_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
             pltpu.VMEM((BQ, 1), jnp.float32),
             pltpu.VMEM((BQ, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)

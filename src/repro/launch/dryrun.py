@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only: never claim a chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production meshes and extract the roofline terms.
@@ -8,9 +9,11 @@ Usage:
     python -m repro.launch.dryrun --arch yi-9b --shape train_4k \
         --mesh single --splice 1 --out results/yi-9b.train_4k.single.json
 
-The XLA_FLAGS line above MUST run before any jax import: jax locks the
-device count at first init.  Smoke tests and benchmarks do NOT import this
-module, so they see the 1 real CPU device.
+The two environment lines above MUST run before any jax import: jax locks
+the platform and device count at first init.  Pinning the CPU platform
+keeps this tool (and every child ``dryrun_all`` starts) off an attached
+TPU, which belongs to one process at a time.  Smoke tests and benchmarks
+do NOT import this module, so they see the 1 real CPU device.
 """
 
 import argparse
@@ -24,7 +27,7 @@ from repro.analysis.hlo import op_histogram
 from repro.analysis.hlo_cost import analyze_hlo
 from repro.analysis.roofline import build_report
 from repro.configs.base import TrainConfig
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.specs import (decode_specs, input_specs, plan_pair,
                                 state_specs)
 from repro.models import decode_step_fn, prefill_fn
@@ -88,7 +91,7 @@ def lower_pair(arch: str, shape_name: str, multi_pod: bool,
     _moe.FUSED_GATE = fused_gate
     _constraints.DISABLE_MODEL_CONSTRAINTS = (shard_profile == "replicate_model")
     if mesh_override is not None:
-        mesh = jax.make_mesh(mesh_override, ("data", "model"))
+        mesh = make_mesh(mesh_override, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size

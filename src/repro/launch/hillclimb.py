@@ -14,6 +14,7 @@ Variants (the §Perf candidate set):
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only: never claim a chip
 
 import argparse
 import json

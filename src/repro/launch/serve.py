@@ -26,6 +26,7 @@ import time
 
 from repro.configs import get_config, get_smoke_config
 from repro.serving.engine import ReplicaProfile
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def plan(args) -> None:
@@ -107,6 +108,7 @@ def main() -> None:
 
     plan(args)
     if not args.plan_only:
+        enable_compile_cache()
         smoke(args)
 
 

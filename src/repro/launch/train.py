@@ -19,6 +19,7 @@ from repro.configs.base import TrainConfig
 from repro.core.checkpoint import CheckpointStore
 from repro.core.elastic import ElasticRuntime
 from repro.core.migration import checkpoint_job
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -38,6 +39,7 @@ def main() -> None:
                     help="step:new_physical (repeatable)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     tcfg = TrainConfig(total_steps=args.steps, warmup_steps=2,

@@ -30,7 +30,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.configs.base import TrainConfig
+from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.checkpoint import CheckpointStore
 from repro.core.elastic import ElasticRuntime
 from repro.core.migration import checkpoint_job
@@ -68,9 +68,17 @@ class ManagedJob:
     preemptions: int = 0
     resizes: int = 0
     steps_done: int = 0
+    # per-step records of every runtime the job has had (preempt barrier
+    # steps included), so the trajectory survives preempt and restore
+    history: List[Dict] = dataclasses.field(default_factory=list)
 
     def demand(self) -> int:
         return self.world_size
+
+    def train_config(self) -> TrainConfig:
+        return TrainConfig(
+            total_steps=self.total_steps, warmup_steps=1, learning_rate=1e-3
+        )
 
 
 def _largest_divisor_leq(world: int, cap: int) -> int:
@@ -135,12 +143,16 @@ class FleetExecutor:
 
     # ------------------------------------------------------------ admission
     def submit(
-        self, job: ManagedJob, global_batch: int = 8, seq_len: int = 32
+        self,
+        job: ManagedJob,
+        global_batch: int = 8,
+        seq_len: int = 32,
+        cfg: Optional[ModelConfig] = None,
     ) -> None:
-        cfg = get_smoke_config(job.arch)
-        tcfg = TrainConfig(
-            total_steps=job.total_steps, warmup_steps=1, learning_rate=1e-3
-        )
+        """Admit ``job`` to the queue; its state is initialised now.
+        ``cfg`` defaults to the reduced smoke config of ``job.arch``."""
+        cfg = cfg or get_smoke_config(job.arch)
+        tcfg = job.train_config()
         job.runtime = ElasticRuntime(
             cfg, tcfg, job.world_size, job.world_size, global_batch, seq_len
         )
@@ -214,7 +226,7 @@ class FleetExecutor:
             if target == 0 and job.allocated > 0:
                 # REAL preemption: in-graph barrier quiesce + checkpoint
                 job.runtime.request_preemption()
-                job.runtime.run_steps(2, stop_on_barrier=True)
+                job.history += job.runtime.run_steps(2, stop_on_barrier=True)
                 job.steps_done = int(job.runtime.state["step"])
                 checkpoint_job(job.runtime, self.store, jid)
                 job.runtime = None
@@ -391,7 +403,7 @@ class FleetExecutor:
         for job in self.jobs.values():
             if job.done or job.runtime is None or job.allocated == 0:
                 continue
-            job.runtime.run_steps(steps)
+            job.history += job.runtime.run_steps(steps)
             job.steps_done = int(job.runtime.state["step"])
             if job.steps_done >= job.total_steps:
                 job.done = True

@@ -6,6 +6,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo_cost import analyze_hlo, parse_module
+from repro.launch.mesh import make_mesh
 from repro.parallel.constraints import constrain
 from repro.parallel.sharding import batch_specs, param_specs
 
@@ -15,7 +16,7 @@ pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 def _mesh22():
     if jax.device_count() < 4:
         pytest.skip("needs >=4 devices (dryrun-only)")
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return make_mesh((2, 2), ("data", "model"))
 
 
 def test_param_specs_megatron_convention():
@@ -23,7 +24,7 @@ def test_param_specs_megatron_convention():
     import jax
     # build a fake mesh object via make_mesh only when possible; otherwise
     # emulate with a 1x1 mesh and assert replicated specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {
         "blocks": {
             "attn": {"wq": jnp.zeros((4, 128, 8, 16)),
@@ -41,7 +42,7 @@ def test_param_specs_megatron_convention():
 
 
 def test_batch_specs_leading_dim():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     batch = {"tokens": jnp.zeros((8, 16), jnp.int32)}
     specs = batch_specs(batch, mesh)
     assert isinstance(specs["tokens"], P)
