@@ -22,13 +22,13 @@ import io
 import json
 import os
 import pickle
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
 from repro.utils.hashing import chunk_checksums
+from repro.utils.profiler import Profiler
 
 CHUNK = 1 << 20     # 1 MiB content chunks (page-dedup granularity)
 
@@ -52,14 +52,22 @@ class SnapshotStats:
     host_logical_bytes: int        # sum of per-worker host dumps
     host_stored_bytes: int         # unique new chunks stored this snapshot
     n_workers: int
-    wall_seconds: float
 
 
 class CheckpointStore:
-    """Content-addressed chunk store + snapshot manifests."""
+    """Content-addressed chunk store + snapshot manifests.
 
-    def __init__(self, root: Optional[str] = None):
+    Spans on ``profiler``: ``ckpt.put`` (a whole snapshot) holding, per
+    device leaf of each worker, ``ckpt.serialize`` and ``ckpt.hash``
+    (chunking, blake2b, dedup lookup, insertion); counters ``ckpt.bytes``
+    and ``ckpt.bytes_new`` (device bytes serialized, and those new to the
+    store).  ``restore.get`` (a whole restore) holding one
+    ``restore.read`` per worker's device copy rebuilt."""
+
+    def __init__(self, root: Optional[str] = None,
+                 profiler: Optional[Profiler] = None):
         self.root = root
+        self.prof = profiler if profiler is not None else Profiler()
         self.chunks: Dict[str, bytes] = {}
         self.manifests: Dict[str, List[Dict]] = {}     # job -> snapshots
         if root:
@@ -117,65 +125,72 @@ class CheckpointStore:
                                 (tracked by the libc SA_Int, §4.4); deduped
                                 by content checksum across workers.
         """
-        t0 = time.time()
-        manifest: Dict = {"job": job_id, "step": step, "workers": {}}
-        dev_logical = dev_stored = host_logical = host_stored = 0
+        prof = self.prof
+        with prof.span("ckpt.put"):
+            manifest: Dict = {"job": job_id, "step": step, "workers": {}}
+            dev_logical = dev_stored = host_logical = host_stored = 0
 
-        for w, tree in device_state_by_worker.items():
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            entries = []
-            for leaf in leaves:
-                data = _leaf_bytes(leaf)
-                dev_logical += len(data)
+            for w, tree in device_state_by_worker.items():
+                leaves, treedef = jax.tree_util.tree_flatten(tree)
+                entries = []
+                for leaf in leaves:
+                    with prof.span("ckpt.serialize"):
+                        data = _leaf_bytes(leaf)
+                    dev_logical += len(data)
+                    with prof.span("ckpt.hash"):
+                        refs, new = self._put_blob(data)
+                    dev_stored += new
+                    entries.append(refs)
+                worker = manifest["workers"].setdefault(str(w), {})
+                worker["device"] = entries
+                worker["treedef"] = pickle.dumps(treedef).hex()
+            prof.add("ckpt.bytes", dev_logical)
+            prof.add("ckpt.bytes_new", dev_stored)
+
+            for w, host in host_state_by_worker.items():
+                data = pickle.dumps(host)
+                host_logical += len(data)
                 refs, new = self._put_blob(data)
-                dev_stored += new
-                entries.append(refs)
-            manifest["workers"].setdefault(str(w), {})["device"] = entries
-            manifest["workers"][str(w)]["treedef"] = pickle.dumps(treedef).hex()
+                host_stored += new
+                manifest["workers"].setdefault(str(w), {})["host"] = refs
 
-        for w, host in host_state_by_worker.items():
-            data = pickle.dumps(host)
-            host_logical += len(data)
-            refs, new = self._put_blob(data)
-            host_stored += new
-            manifest["workers"].setdefault(str(w), {})["host"] = refs
+            if files_by_worker:
+                for w, files in files_by_worker.items():
+                    fl = {}
+                    for path, content in files.items():
+                        refs, new = self._put_blob(content)
+                        host_stored += new
+                        fl[path] = refs
+                    manifest["workers"].setdefault(str(w), {})["files"] = fl
 
-        if files_by_worker:
-            for w, files in files_by_worker.items():
-                fl = {}
-                for path, content in files.items():
-                    refs, new = self._put_blob(content)
-                    host_stored += new
-                    fl[path] = refs
-                manifest["workers"].setdefault(str(w), {})["files"] = fl
-
-        self.manifests.setdefault(job_id, []).append(manifest)
-        if self.root:
-            path = os.path.join(self.root, f"{job_id}.manifests.json")
-            with open(path, "w") as f:
-                json.dump(self.manifests[job_id], f, default=str)
-        return SnapshotStats(
-            step=step, device_logical_bytes=dev_logical,
-            device_stored_bytes=dev_stored, host_logical_bytes=host_logical,
-            host_stored_bytes=host_stored,
-            n_workers=len(device_state_by_worker),
-            wall_seconds=time.time() - t0)
+            self.manifests.setdefault(job_id, []).append(manifest)
+            if self.root:
+                path = os.path.join(self.root, f"{job_id}.manifests.json")
+                with open(path, "w") as f:
+                    json.dump(self.manifests[job_id], f, default=str)
+            return SnapshotStats(
+                step=step, device_logical_bytes=dev_logical,
+                device_stored_bytes=dev_stored, host_logical_bytes=host_logical,
+                host_stored_bytes=host_stored,
+                n_workers=len(device_state_by_worker))
 
     # --------------------------------------------------------------- restore
     def restore(self, job_id: str, step: Optional[int] = None
                 ) -> Tuple[Dict[int, Any], Dict[int, Dict], int]:
         """Returns (device_state_by_worker, host_state_by_worker, step)."""
-        snaps = self.manifests[job_id]
-        manifest = snaps[-1] if step is None else \
-            next(m for m in snaps if m["step"] == step)
-        device, host = {}, {}
-        for w, entry in manifest["workers"].items():
-            treedef = pickle.loads(bytes.fromhex(entry["treedef"]))
-            leaves = [_leaf_from_bytes(self._get_blob(refs))
-                      for refs in entry["device"]]
-            device[int(w)] = jax.tree_util.tree_unflatten(treedef, leaves)
-            host[int(w)] = pickle.loads(self._get_blob(entry["host"]))
-        return device, host, manifest["step"]
+        with self.prof.span("restore.get"):
+            snaps = self.manifests[job_id]
+            manifest = snaps[-1] if step is None else \
+                next(m for m in snaps if m["step"] == step)
+            device, host = {}, {}
+            for w, entry in manifest["workers"].items():
+                treedef = pickle.loads(bytes.fromhex(entry["treedef"]))
+                with self.prof.span("restore.read"):
+                    leaves = [_leaf_from_bytes(self._get_blob(refs))
+                              for refs in entry["device"]]
+                device[int(w)] = jax.tree_util.tree_unflatten(treedef, leaves)
+                host[int(w)] = pickle.loads(self._get_blob(entry["host"]))
+            return device, host, manifest["step"]
 
     # ----------------------------------------------------------------- sizes
     def stored_bytes(self) -> int:

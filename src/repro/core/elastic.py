@@ -9,10 +9,24 @@ ZeRO partial sharding (§5.4): a job whose optimizer state is sharded
 ``zero_shard_factor``-way can only be spliced up to W / shard_factor — the
 runtime enforces the paper's placement rule (only replicas of the same
 shard are spliced together).
+
+Spans on the runtime's ``profiler`` (``repro.utils.profiler``):
+
+- ``step.batch`` builds a step's batch on the host; ``step.dispatch``
+  calls the step program until it returns (enqueued, not finished);
+  ``step.wait`` reads back what the host needs of the step (barrier
+  flags, step counter, loss), so it ends when the step has run.
+- ``step.build.<cause>`` takes the place of ``step.dispatch`` on the
+  first call of each step program the runtime builds: trace, lowering,
+  compile or cache load, and enqueue.  ``<cause>`` says why the runtime
+  needed the program: ``admit`` (a fresh runtime), ``restore``
+  (``from_snapshot``) or ``resize``.
+- ``ckpt.d2h`` copies the state to the host for a snapshot;
+  ``restore.h2d`` copies a snapshot's state to the device, and waits for
+  the copy only when the profiler is enabled.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import jax
@@ -26,6 +40,7 @@ from repro.models.frontend import synth_extra_inputs
 from repro.optim.zero import validate_partial_sharding
 from repro.training.state import TrainState, init_train_state
 from repro.training.step import build_train_step
+from repro.utils.profiler import Profiler
 
 
 class ElasticRuntime:
@@ -35,7 +50,8 @@ class ElasticRuntime:
     def __init__(self, cfg: ModelConfig, tcfg: TrainConfig, world_size: int,
                  physical_devices: int, global_batch: int, seq_len: int,
                  seed: int = 0, state: Optional[TrainState] = None,
-                 pipeline_state: Optional[Dict] = None):
+                 pipeline_state: Optional[Dict] = None,
+                 profiler: Optional[Profiler] = None):
         assert world_size % physical_devices == 0
         self.cfg = cfg
         self.tcfg = tcfg
@@ -53,8 +69,12 @@ class ElasticRuntime:
         self.barrier = BarrierDriver(n_shards=1)
         self._extra_key = jax.random.PRNGKey(tcfg.seed + 1)
         self._steps: Dict[int, any] = {}
+        # why the next program built is needed, and per splice the cause
+        # of a program built and not yet called
+        self._cause = "admit"
+        self._unbuilt: Dict[int, str] = {}
         self.history: List[Dict] = []
-        self.compile_seconds = 0.0
+        self.prof = profiler if profiler is not None else Profiler()
 
     # ------------------------------------------------------------------ step
     @property
@@ -64,11 +84,9 @@ class ElasticRuntime:
     def _step_fn(self):
         s = self.splice
         if s not in self._steps:
-            t0 = time.time()
-            fn = jax.jit(build_train_step(self.cfg, self.tcfg, splice=s,
-                                          with_barrier=True))
-            self._steps[s] = fn
-            self.compile_seconds += time.time() - t0
+            self._steps[s] = jax.jit(build_train_step(
+                self.cfg, self.tcfg, splice=s, with_barrier=True))
+            self._unbuilt[s] = self._cause
         return self._steps[s]
 
     # ----------------------------------------------------- preemption flow
@@ -83,10 +101,12 @@ class ElasticRuntime:
         return self.barrier.acquired
 
     def _batch(self) -> Dict:
-        tokens, labels = self.pipeline.next_batch()
-        batch = {"tokens": jnp.asarray(tokens), "labels": jnp.asarray(labels)}
-        batch.update(synth_extra_inputs(self.cfg, tokens.shape[0],
-                                        self._extra_key))
+        with self.prof.span("step.batch"):
+            tokens, labels = self.pipeline.next_batch()
+            batch = {"tokens": jnp.asarray(tokens),
+                     "labels": jnp.asarray(labels)}
+            batch.update(synth_extra_inputs(self.cfg, tokens.shape[0],
+                                            self._extra_key))
         return batch
 
     def run_steps(self, n: int, stop_on_barrier: bool = False) -> List[Dict]:
@@ -94,10 +114,16 @@ class ElasticRuntime:
         fn = self._step_fn()
         for _ in range(n):
             batch = self._batch()
-            self.state, metrics = fn(self.state, batch, self.barrier.flags())
-            acquired = self.barrier.observe(metrics["barrier"])
-            rec = {"step": int(self.state["step"]),
-                   "loss": float(metrics["loss"]),
+            cause = self._unbuilt.pop(self.splice, None)
+            name = "step.dispatch" if cause is None else "step.build." + cause
+            with self.prof.span(name):
+                self.state, metrics = fn(self.state, batch,
+                                         self.barrier.flags())
+            with self.prof.span("step.wait"):
+                acquired = self.barrier.observe(metrics["barrier"])
+                step, loss = int(self.state["step"]), float(metrics["loss"])
+            rec = {"step": step,
+                   "loss": loss,
                    "splice": self.splice,
                    "physical": self.physical,
                    "barrier_acquired": acquired}
@@ -118,28 +144,37 @@ class ElasticRuntime:
         validate_partial_sharding(self.world_size, self.tcfg.zero_shard_factor,
                                   self.world_size // new_physical)
         old = self.physical
-        t0 = time.time()
         self.physical = new_physical
-        self._step_fn()     # build/compile the new splice's step
+        if self._steps:     # a runtime yet to run its first step is still
+            self._cause = "resize"      # being admitted or restored
+        self._step_fn()     # the new splice's step, built on its first call
         return {"from": old, "to": new_physical,
                 "splice": self.splice,
-                "resize_seconds": time.time() - t0,
                 "at_step": int(self.state["step"])}
 
     # ------------------------------------------------------------- snapshots
     def snapshot(self) -> Dict:
         """The complete program state (work-conserving checkpoint payload)."""
+        with self.prof.span("ckpt.d2h"):
+            state = jax.tree_util.tree_map(np.asarray, self.state)
         return {
-            "state": jax.tree_util.tree_map(np.asarray, self.state),
+            "state": state,
             "pipeline": self.pipeline.snapshot(),
             "world_size": self.world_size,
         }
 
     @classmethod
     def from_snapshot(cls, cfg: ModelConfig, tcfg: TrainConfig, snap: Dict,
-                      physical_devices: int, global_batch: int, seq_len: int
+                      physical_devices: int, global_batch: int, seq_len: int,
+                      profiler: Optional[Profiler] = None
                       ) -> "ElasticRuntime":
-        state = jax.tree_util.tree_map(jnp.asarray, snap["state"])
-        return cls(cfg, tcfg, snap["world_size"], physical_devices,
-                   global_batch, seq_len, state=state,
-                   pipeline_state=snap["pipeline"])
+        prof = profiler if profiler is not None else Profiler()
+        with prof.span("restore.h2d"):
+            state = jax.tree_util.tree_map(jnp.asarray, snap["state"])
+            if prof.enabled:    # the copy's time, not its enqueue
+                jax.block_until_ready(state)
+        rt = cls(cfg, tcfg, snap["world_size"], physical_devices,
+                 global_batch, seq_len, state=state,
+                 pipeline_state=snap["pipeline"], profiler=prof)
+        rt._cause = "restore"
+        return rt

@@ -69,8 +69,10 @@ def main() -> None:
         print(f"step {rec['step']:4d} loss={rec['loss']:.4f} "
               f"splice={rec['splice']} physical={rec['physical']}")
     wall = time.time() - t0
+    build = sum(t for name, t in rt.prof.totals.items()
+                if name.startswith("step.build."))
     print(f"done: {args.steps} steps in {wall:.1f}s "
-          f"(compile {rt.compile_seconds:.1f}s)")
+          f"(step programs built in {build:.1f}s)")
     if args.out:
         with open(args.out, "w") as f:
             json.dump({"history": rt.history, "events": events,

@@ -21,6 +21,13 @@ longer drift apart.
 Capacity is counted in "device slots"; each job's logical world size stays
 constant while its physical allocation follows the policy, rounded to the
 nearest world-size divisor (the splice constraint s = W/P).
+
+One ``Profiler`` (``repro.utils.profiler``) times the run: the
+telemetry's when one is given, else a disabled one of the executor's
+own.  The policy, the checkpoint store and every runtime the executor
+builds share it, so the decide pass, the mechanisms and the step loop
+land in one tree of spans.  The executor adds ``preempt.barrier``: the
+preemption request through the barrier steps that quiesce the job.
 """
 from __future__ import annotations
 
@@ -53,6 +60,7 @@ from repro.scheduler.telemetry import (
     FleetTelemetry,
 )
 from repro.scheduler.types import Cluster, Fleet, Job, Region
+from repro.utils.profiler import Profiler
 
 
 @dataclasses.dataclass
@@ -103,7 +111,6 @@ class FleetExecutor:
     ):
         self.total_slots = total_slots
         self.jobs: Dict[str, ManagedJob] = {}
-        self.store = CheckpointStore()
         self.log: List[Dict] = []
         # observability: the same structured event log / profiler bundle
         # the simulator threads (telemetry.py) — pass ``True`` to build a
@@ -114,6 +121,8 @@ class FleetExecutor:
             telemetry = FleetTelemetry()
         self.tele: Optional[FleetTelemetry] = telemetry or None
         self._ev = self.tele.events if self.tele is not None else None
+        self.prof = self.tele.prof if self.tele is not None else Profiler()
+        self.store = CheckpointStore(profiler=self.prof)
         # the same policy object the simulator drives, over a 1-cluster fleet
         self.policy = policy or ElasticPolicy()
         # thread the mechanism cost model into the policy so the executor's
@@ -121,8 +130,8 @@ class FleetExecutor:
         self.cost_model = cost_model or CostModel()
         if hasattr(self.policy, "bind_costs"):
             self.policy.bind_costs(self.cost_model, tick_seconds)
-        if self.tele is not None and hasattr(self.policy, "bind_telemetry"):
-            self.policy.bind_telemetry(self.tele)
+        if hasattr(self.policy, "prof"):
+            self.policy.prof = self.prof
         # shadow accounts live in a shared fleet ledger, and the shadows
         # themselves in a shared JobTable, like the simulator's — one
         # decide path for both back-ends, column slices included
@@ -154,7 +163,13 @@ class FleetExecutor:
         cfg = cfg or get_smoke_config(job.arch)
         tcfg = job.train_config()
         job.runtime = ElasticRuntime(
-            cfg, tcfg, job.world_size, job.world_size, global_batch, seq_len
+            cfg,
+            tcfg,
+            job.world_size,
+            job.world_size,
+            global_batch,
+            seq_len,
+            profiler=self.prof,
         )
         job._cfg, job._tcfg = cfg, tcfg
         job._gb, job._sl = global_batch, seq_len
@@ -225,9 +240,10 @@ class FleetExecutor:
                 continue
             if target == 0 and job.allocated > 0:
                 # REAL preemption: in-graph barrier quiesce + checkpoint
-                job.runtime.request_preemption()
-                job.history += job.runtime.run_steps(2, stop_on_barrier=True)
-                job.steps_done = int(job.runtime.state["step"])
+                with self.prof.span("preempt.barrier"):
+                    job.runtime.request_preemption()
+                    job.history += job.runtime.run_steps(2, stop_on_barrier=True)
+                    job.steps_done = int(job.runtime.state["step"])
                 checkpoint_job(job.runtime, self.store, jid)
                 job.runtime = None
                 job.preemptions += 1
@@ -251,7 +267,13 @@ class FleetExecutor:
                 if jid not in self.store.manifests:
                     # failed before any checkpoint existed: fresh restart
                     job.runtime = ElasticRuntime(
-                        job._cfg, job._tcfg, job.world_size, target, job._gb, job._sl
+                        job._cfg,
+                        job._tcfg,
+                        job.world_size,
+                        target,
+                        job._gb,
+                        job._sl,
+                        profiler=self.prof,
                     )
                     job.steps_done = 0
                     shadow = self._shadows[jid]
@@ -285,6 +307,7 @@ class FleetExecutor:
                     target,
                     job._gb,
                     job._sl,
+                    profiler=self.prof,
                 )
                 assert int(job.runtime.state["step"]) == job.steps_done
                 self.log.append({"event": "restore", "job": jid, "at_step": step})

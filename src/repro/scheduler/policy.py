@@ -100,7 +100,7 @@ from repro.scheduler.node_map import (
     gang_values,
     splice_divisors,
 )
-from repro.scheduler.telemetry import Profiler
+from repro.utils.profiler import Profiler
 from repro.scheduler.types import Fleet, Job
 
 DEFAULT_INTERVAL_SECONDS = 300.0
@@ -352,11 +352,10 @@ class ElasticPolicy:
         self.aging_threshold_intervals = aging_threshold_intervals
         self._bound_cost = False
         self._bound_interval = False
-        # unified decide-pass profiler (telemetry.Profiler).  Totals
-        # always accumulate at the exact cost of the old ad-hoc
-        # ``gather_seconds``/``node_seconds`` fields (two perf_counter
-        # calls per span); per-span records for trace export are kept
-        # only once a FleetTelemetry is bound via ``bind_telemetry``.
+        # decide-pass profiler (utils.profiler.Profiler).  Totals always
+        # accumulate (two perf_counter calls per span); per-span records
+        # for trace export are kept only once a FleetTelemetry is bound
+        # via ``bind_telemetry`` or an executor hands over its own.
         self.prof = Profiler()
 
     @property

@@ -20,12 +20,12 @@ attribution first-class:
 - ``MetricsSeries`` — one row per scheduler tick (utilization, queue
   depth by tier, stranded GPUs, goodput, SLO attainment, loaned GPUs,
   decide-latency breakdown) in doubling float columns, CSV/JSON dump.
-- ``Profiler`` — nested named spans replacing the ad-hoc
-  ``decide_seconds`` / ``gather_seconds`` / ``node_seconds`` fields in
-  ``policy.py``.  Per-name totals are always accumulated (two
-  ``perf_counter`` calls per span, the same cost as the old fields);
-  span *records* for trace export are only kept when the profiler is
-  enabled, so telemetry-off runs stay near-zero-cost.
+- ``Profiler`` (``repro.utils.profiler``, re-exported here) — nested
+  named spans and counters: the decide pass's phases, and the
+  executor's mechanisms and step loop.  Per-name totals are always
+  accumulated (two ``perf_counter`` calls per span); span *records* for
+  trace export are only kept when the profiler is enabled, so
+  telemetry-off runs stay near-zero-cost.
 - ``export_chrome_trace`` — Chrome/Perfetto trace-event JSON: job
   lifecycle spans on per-cluster tracks (pid = cluster, tid = job slot)
   plus decide-pass phase spans on a scheduler track, wired up as
@@ -43,13 +43,13 @@ in CI pins that decision digests are byte-identical with telemetry on.
 from __future__ import annotations
 
 import json
-import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.sla import TIERS
 from repro.scheduler.reliability import FAILURE_KINDS
+from repro.utils.profiler import Profiler
 
 TIER_NAMES = list(TIERS)
 
@@ -346,80 +346,12 @@ class MetricsSeries:
             )
 
 
-# ---------------------------------------------------------------- profiler
-class _Span:
-    """One live nested span; re-entered via ``with prof.span(name)``."""
-
-    __slots__ = ("prof", "name", "t0", "depth")
-
-    def __init__(self, prof: "Profiler", name: str):
-        self.prof = prof
-        self.name = name
-
-    def __enter__(self) -> "_Span":
-        p = self.prof
-        self.depth = p._depth
-        p._depth = self.depth + 1
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter()
-        p = self.prof
-        p._depth = self.depth
-        p.totals[self.name] = p.totals.get(self.name, 0.0) + (t1 - self.t0)
-        p.counts[self.name] = p.counts.get(self.name, 0) + 1
-        if p.enabled:
-            p.spans.append(
-                (self.name, self.depth, p._anchor, p._anchor_wall, self.t0, t1)
-            )
-
-
-class Profiler:
-    """Nested named wall-clock spans.
-
-    Totals (``total(name)``) accumulate whether or not the profiler is
-    enabled — they back ``ElasticPolicy.gather_seconds`` /
-    ``node_seconds`` at the exact cost of the old ad-hoc
-    ``perf_counter`` pairs.  Span *records* (for Perfetto export) are
-    only kept when ``enabled``; a disabled profiler records nothing.
-
-    ``set_anchor(sim_time)`` pins the current simulated time so wall
-    durations can be projected onto the simulation timeline at export.
-    """
-
-    def __init__(self, enabled: bool = False):
-        self.enabled = enabled
-        self.totals: Dict[str, float] = {}
-        self.counts: Dict[str, int] = {}
-        # (name, depth, anchor_sim, anchor_wall, t0, t1)
-        self.spans: List[Tuple[str, int, float, float, float, float]] = []
-        self._depth = 0
-        self._anchor = 0.0
-        self._anchor_wall = 0.0
-
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
-
-    def total(self, name: str) -> float:
-        return self.totals.get(name, 0.0)
-
-    def set_anchor(self, sim_time: float) -> None:
-        self._anchor = float(sim_time)
-        self._anchor_wall = time.perf_counter()
-
-    def reset(self) -> None:
-        self.totals.clear()
-        self.counts.clear()
-        self.spans.clear()
-        self._depth = 0
-
-
 class FleetTelemetry:
     """The bundle a simulator (or executor) run emits into.
 
     ``events`` is the structured lifecycle log, ``metrics`` the per-tick
-    series, ``prof`` the (enabled) decide-pass profiler.  ``meta``
+    series, ``prof`` the (enabled) profiler of the decide pass and, under
+    the executor, of the mechanisms and the step loop.  ``meta``
     collects run facts (reliability on/off, cluster names, ...) that the
     JSONL export and the replay check consume.
     """
