@@ -14,6 +14,12 @@ The checkpoint of an N-worker job is ``S_G + N * S_pwCr`` (paper §7.2):
 
 Chunks are content-addressed (blake2b-128); a snapshot is a manifest of
 chunk references.  The store can live in memory or on disk.
+
+A device leaf's blob is the one ``np.save`` writes: the npy header, then
+the data.  The store never builds that blob: it hashes each 1 MiB chunk of
+it straight from the leaf's own buffer (one contiguous copy only where the
+leaf's memory is not already ``np.save``'s data order), on a thread pool,
+and copies only the chunks new to the store.
 """
 from __future__ import annotations
 
@@ -22,22 +28,19 @@ import io
 import json
 import os
 import pickle
-from typing import Any, Dict, List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from numpy.lib import format as npy_format
 
-from repro.utils.hashing import chunk_checksums
+from repro.utils.hashing import chunk_digest
 from repro.utils.profiler import Profiler
 
 CHUNK = 1 << 20     # 1 MiB content chunks (page-dedup granularity)
-
-
-def _leaf_bytes(leaf) -> bytes:
-    arr = np.asarray(leaf)
-    buf = io.BytesIO()
-    np.save(buf, arr, allow_pickle=False)
-    return buf.getvalue()
+HASH_THREADS = 8    # cap on the store's pool (blake2b and numpy copies
+                    # release the GIL)
 
 
 def _leaf_from_bytes(b: bytes):
@@ -58,36 +61,63 @@ class CheckpointStore:
     """Content-addressed chunk store + snapshot manifests.
 
     Spans on ``profiler``: ``ckpt.put`` (a whole snapshot) holding, per
-    device leaf of each worker, ``ckpt.serialize`` and ``ckpt.hash``
-    (chunking, blake2b, dedup lookup, insertion); counters ``ckpt.bytes``
-    and ``ckpt.bytes_new`` (device bytes serialized, and those new to the
-    store).  ``restore.get`` (a whole restore) holding one
-    ``restore.read`` per worker's device copy rebuilt."""
+    device leaf of each worker, ``ckpt.serialize`` (the npy header and a
+    view of the leaf's buffer, or its one contiguous copy) and
+    ``ckpt.hash`` (on the pool, the chunks' blake2b and then the copy of
+    those new to the store; on the caller, the dedup and insertion);
+    counters ``ckpt.bytes``, ``ckpt.bytes_new`` and ``ckpt.bytes_inplace``
+    (device bytes of the npy blobs; of those, new to the store; of those,
+    hashed straight from a leaf's own buffer).  ``restore.get`` (a whole
+    restore) holding one ``restore.read`` per worker's device copy
+    rebuilt."""
 
     def __init__(self, root: Optional[str] = None,
                  profiler: Optional[Profiler] = None):
         self.root = root
         self.prof = profiler if profiler is not None else Profiler()
-        self.chunks: Dict[str, bytes] = {}
+        # a put's new chunks are views of one buffer the store owns;
+        # chunks read back from disk are bytes
+        self.chunks: Dict[str, bytes | memoryview] = {}
         self.manifests: Dict[str, List[Dict]] = {}     # job -> snapshots
+        self._pool: Optional[ThreadPoolExecutor] = None
         if root:
             os.makedirs(os.path.join(root, "chunks"), exist_ok=True)
 
     # ---------------------------------------------------------------- chunks
-    def _put_chunk(self, data: bytes) -> Tuple[str, bool]:
-        cs = chunk_checksums(data, len(data) or 1)[0] if len(data) <= CHUNK \
-            else None
-        if cs is None:
-            raise ValueError("chunk too large")
-        new = cs not in self.chunks
-        if new:
-            self.chunks[cs] = data
-            if self.root:
-                with open(os.path.join(self.root, "chunks", cs), "wb") as f:
-                    f.write(data)
-        return cs, new
+    def _map(self, fn, items: Sequence) -> List:
+        """``fn`` over ``items`` in order; on the store's pool unless one
+        item makes the whole work."""
+        if len(items) <= 1:
+            return [fn(i) for i in items]
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(
+                max_workers=min(os.cpu_count() or 1, HASH_THREADS),
+                thread_name_prefix="ckpt-hash")
+        return list(self._pool.map(fn, items))
 
-    def _get_chunk(self, cs: str) -> bytes:
+    def _npy_parts(self, leaf) -> Tuple[bytes, memoryview, bool]:
+        """``np.save``'s bytes of ``leaf``, unjoined: (npy header, data, in
+        place).  The data is a view of the leaf's own buffer where that
+        buffer already holds ``np.save``'s data bytes (C order, or F order
+        through ``.T``); any other leaf takes one C-order copy (``in
+        place`` False), made on the pool in slabs along its first axis."""
+        arr = np.asarray(leaf)
+        head = io.BytesIO()
+        npy_format._write_array_header(
+            head, npy_format.header_data_from_array_1_0(arr))
+        if arr.flags.c_contiguous:
+            flat, in_place = arr, True
+        elif arr.flags.f_contiguous:
+            flat, in_place = arr.T, True
+        else:
+            flat, in_place = np.empty(arr.shape, arr.dtype), False
+            rows = -(-len(arr) // HASH_THREADS)
+            self._map(lambda i: np.copyto(flat[i:i + rows], arr[i:i + rows]),
+                      range(0, len(arr), rows))
+        return head.getvalue(), memoryview(flat.reshape(-1).view(np.uint8)), \
+            in_place
+
+    def _get_chunk(self, cs: str) -> bytes | memoryview:
         if cs in self.chunks:
             return self.chunks[cs]
         if self.root:
@@ -97,16 +127,50 @@ class CheckpointStore:
             return data
         raise KeyError(cs)
 
-    def _put_blob(self, data: bytes) -> Tuple[List[str], int]:
-        """Store a blob as content chunks; returns (chunk refs, new bytes)."""
-        refs, new_bytes = [], 0
-        for i in range(0, max(len(data), 1), CHUNK):
-            piece = data[i:i + CHUNK]
-            cs, new = self._put_chunk(piece)
-            refs.append(cs)
-            if new:
-                new_bytes += len(piece)
-        return refs, new_bytes
+    def _put_blob(self, data, head: bytes = b"") -> Tuple[List[str], int]:
+        """Store the blob ``head + data`` as content chunks without joining
+        it: chunk k is bytes [k * CHUNK, (k + 1) * CHUNK) of the blob.
+        The pool hashes the chunks; the caller picks, in chunk order, those
+        new to the store; the pool copies them into one buffer the store
+        owns, and each is stored as a view of it.  Returns (chunk refs,
+        new bytes)."""
+        data = memoryview(data)
+        n_head, size = len(head), len(head) + data.nbytes
+
+        def parts(k):
+            a, b = k * CHUNK, min((k + 1) * CHUNK, size)
+            if a >= n_head:
+                return (data[a - n_head:b - n_head],)
+            return head[a:b], data[:max(b - n_head, 0)]
+
+        refs = self._map(lambda k: chunk_digest(*parts(k)),
+                         range(max(-(-size // CHUNK), 1)))
+        new, seen, at = [], set(), [0]
+        for k, cs in enumerate(refs):     # in chunk order, as inserted
+            if cs not in self.chunks and cs not in seen:
+                seen.add(cs)
+                new.append(k)
+                at.append(at[-1] + min(CHUNK, size - k * CHUNK))
+        # one buffer for the new chunks: numpy allocates it in huge pages
+        # and copies into it with the GIL released
+        buf = np.empty(at[-1], np.uint8)
+
+        def copy(i):
+            pos = at[i]
+            for part in parts(new[i]):
+                buf[pos:pos + len(part)] = np.frombuffer(part, np.uint8)
+                pos += len(part)
+
+        self._map(copy, range(len(new)))
+        view = memoryview(buf)
+        for i, k in enumerate(new):
+            piece = view[at[i]:at[i + 1]]
+            self.chunks[refs[k]] = piece
+            if self.root:
+                with open(os.path.join(self.root, "chunks", refs[k]),
+                          "wb") as f:
+                    f.write(piece)
+        return refs, at[-1]
 
     def _get_blob(self, refs: List[str]) -> bytes:
         return b"".join(self._get_chunk(c) for c in refs)
@@ -128,17 +192,20 @@ class CheckpointStore:
         prof = self.prof
         with prof.span("ckpt.put"):
             manifest: Dict = {"job": job_id, "step": step, "workers": {}}
-            dev_logical = dev_stored = host_logical = host_stored = 0
+            dev_logical = dev_stored = dev_inplace = 0
+            host_logical = host_stored = 0
 
             for w, tree in device_state_by_worker.items():
                 leaves, treedef = jax.tree_util.tree_flatten(tree)
                 entries = []
                 for leaf in leaves:
                     with prof.span("ckpt.serialize"):
-                        data = _leaf_bytes(leaf)
-                    dev_logical += len(data)
+                        head, data, in_place = self._npy_parts(leaf)
+                    size = len(head) + data.nbytes
+                    dev_logical += size
+                    dev_inplace += size if in_place else 0
                     with prof.span("ckpt.hash"):
-                        refs, new = self._put_blob(data)
+                        refs, new = self._put_blob(data, head)
                     dev_stored += new
                     entries.append(refs)
                 worker = manifest["workers"].setdefault(str(w), {})
@@ -146,6 +213,7 @@ class CheckpointStore:
                 worker["treedef"] = pickle.dumps(treedef).hex()
             prof.add("ckpt.bytes", dev_logical)
             prof.add("ckpt.bytes_new", dev_stored)
+            prof.add("ckpt.bytes_inplace", dev_inplace)
 
             for w, host in host_state_by_worker.items():
                 data = pickle.dumps(host)

@@ -23,10 +23,12 @@ def buffer_checksum(arr: Any) -> str:
     return h.hexdigest()
 
 
-def chunk_checksums(data: bytes, chunk_size: int = 1 << 20):
-    """Content checksums of fixed-size chunks (CRIU page-dedup analogue)."""
-    out = []
-    for i in range(0, len(data), chunk_size):
-        h = hashlib.blake2b(data[i:i + chunk_size], digest_size=16)
-        out.append(h.hexdigest())
-    return out
+def chunk_digest(*parts) -> str:
+    """Content address of one checkpoint chunk: blake2b-128 of the
+    concatenation of ``parts`` (bytes-like), fed in order without joining
+    them.  ``hashlib`` releases the GIL on large buffers, so chunks hash in
+    parallel on threads."""
+    h = hashlib.blake2b(digest_size=16)
+    for part in parts:
+        h.update(part)
+    return h.hexdigest()

@@ -77,6 +77,8 @@ def test_spans_and_counters_of_a_churn_cycle(runs):
     assert p.counts["ckpt.serialize"] == p.counts["ckpt.hash"] == leaves * WORLD
     # workers 2..W hold the same state: their copies add no new bytes
     assert 0 < p.counters["ckpt.bytes_new"] <= p.counters["ckpt.bytes"] / WORLD
+    # every leaf of the state is hashed straight from its own buffer
+    assert p.counters["ckpt.bytes_inplace"] == p.counters["ckpt.bytes"]
     assert p.counts["restore.get"] == p.counts["restore.h2d"] == 1
     assert p.counts["restore.read"] == WORLD
     assert p.counts["step.build.restore"] == p.counts["step.build.resize"] == 1
