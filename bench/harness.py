@@ -13,6 +13,7 @@ the reference runs after the window, once the program's state is freed.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import json
 import math
@@ -20,6 +21,7 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -42,12 +44,43 @@ class NoChip(RuntimeError):
     pass
 
 
+def _frozen(value):
+    """JSON lists as tuples, at any depth, so that the value hashes."""
+    if isinstance(value, list):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+def _dataclass_of(hint):
+    """The dataclass a field holds, through ``Optional``; else ``None``."""
+    if dataclasses.is_dataclass(hint):
+        return hint
+    args = [a for a in typing.get_args(hint) if a is not type(None)]
+    if typing.get_origin(hint) is typing.Union and len(args) == 1:
+        return _dataclass_of(args[0])
+    return None
+
+
+def build(cls, block: Dict):
+    """Dataclass ``cls`` from a JSON object: a key whose field holds a
+    dataclass (``ModelConfig.moe``, ``.ssm``, ...) is built into it, lists
+    become tuples, and a key ``cls`` has no field for raises."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    unknown = set(block) - names
+    if unknown:
+        raise TypeError(f"{cls.__name__} has no field {sorted(unknown)}")
+    fields = {}
+    for key, value in block.items():
+        sub = _dataclass_of(hints[key])
+        fields[key] = (build(sub, value) if sub is not None
+                       and isinstance(value, dict) else _frozen(value))
+    return cls(**fields)
+
+
 def model_config(config: Dict):
-    from repro.configs.base import ModelConfig, SSMConfig
-    fields = dict(config["model"])
-    if "ssm" in fields:
-        fields["ssm"] = SSMConfig(**fields["ssm"])
-    return ModelConfig(**fields)
+    from repro.configs.base import ModelConfig
+    return build(ModelConfig, config["model"])
 
 
 def check_train_config(tcfg, train: Dict) -> None:
@@ -272,6 +305,21 @@ class Compiles:
         jax.monitoring.unregister_event_listener(self._hit)
 
 
+def program_totals(prof) -> Dict[str, Dict]:
+    """A copy of what the program's own ``Profiler`` has accumulated: per
+    span its seconds and calls, and its counters.  It is read, never reset
+    or switched on, so the program runs as it does without the benchmark."""
+    return {"totals": dict(prof.totals), "counts": dict(prof.counts),
+            "counters": dict(prof.counters)}
+
+
+def program_window(before: Dict, after: Dict) -> Dict[str, Dict]:
+    """What the program's spans and counters added between two copies; a
+    span that ran only before the first copy reads 0 calls."""
+    return {part: {k: v - before[part].get(k, 0) for k, v in after[part].items()}
+            for part in after}
+
+
 class Run:
     """Set-up state of one run: the executor, its traffic driver, the
     probes and the followed job's readings."""
@@ -324,6 +372,7 @@ class Run:
         history0 = {jid: len(j.history) for jid, j in self.ex.jobs.items()}
         self.probes.reset()
         c0 = self.compiles.counts()
+        prog0 = program_totals(self.ex.prof)
         if trace_dir:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -338,6 +387,7 @@ class Run:
             while time.perf_counter() - t0 < seconds:
                 self.drv.tick()
         t1 = time.perf_counter()
+        program = program_window(prog0, program_totals(self.ex.prof))
         if trace_dir:
             jax.profiler.stop_trace()
             self.probes.annotate = False
@@ -350,7 +400,7 @@ class Run:
             tokens += len(new) * self.gb * self.sl
         return {"window_s": t1 - t0, "steps": steps, "failed": bad,
                 "tokens": tokens, "programs_loaded": loaded,
-                "compiles_in_window": compiled,
+                "compiles_in_window": compiled, "program": program,
                 "events": {k: list(v) for k, v in self.probes.events.items()},
                 "event_ends": {k: [t - t0 for t in v] for k, v in
                                self.probes.event_ends.items()},
@@ -451,6 +501,10 @@ def run(cell: specs.Cell, seed: int, seconds: float, trace: bool,
                    "dispatch_s_percentiles": _percentiles(
                        w["spans"].get("dispatch")),
                    "spans": {k: [len(v), sum(v)] for k, v in w["spans"].items()},
+                   "program": {
+                       "spans": {k: [n, w["program"]["totals"][k]] for k, n in
+                                 w["program"]["counts"].items() if n},
+                       "counters": w["program"]["counters"]},
                    "gaps": gaps(r.readings, ref),
                    "losses": r.readings["losses"],
                    "reference_losses": ref["losses"]}

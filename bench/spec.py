@@ -1,6 +1,8 @@
 """Loading by name: a cell of ``BENCHMARK.json`` and the files it names.
 
-- configuration ``<name>``: ``bench/configs/<name>.json``
+- configuration ``<name>``: ``bench/configs/<name>.json``; its ``model``
+  block is built into ``ModelConfig`` by ``harness.build``, each key whose
+  field holds a dataclass (``moe``, ``ssm``, ...) into that dataclass
 - traffic mix ``<name>``: ``bench/traffic/<name>.json``
 - correctness limits of cell ``<name>``: ``bench/limits/<name>.json``
 - metric ``<name>``: ``bench/metrics/<name>.py``, whose ``read(ctx)``
@@ -9,9 +11,17 @@
   bound, differs), is read by ``bench/metrics/<base>.py`` unless a file of
   its full name exists
 - plain reference ``<name>``: ``bench/references/<name>.py``
+- FLOP count ``<name>`` (a configuration's ``flops``):
+  ``bench/counts/<name>.py``, whose ``per_token(model, seq_len)`` gives
+  the model FLOPs of one trained token (``flops.py`` states what counts);
+  the same file holds the operation and byte counts of that family's
+  kernels
+- the program's own spans and counters over the window: ``ctx["program"]``
+  of a metric's ``read``, ``{"totals", "counts", "counters"}`` from the
+  executor's ``Profiler`` (``harness.program_window``)
 
-A later cell or metric is added by adding files and entries; no file here
-changes for it.
+A later configuration, cell or metric is added by adding files and
+entries; no file here changes for it.
 """
 from __future__ import annotations
 
@@ -65,6 +75,10 @@ def metric_reader(name: str) -> Callable:
 
 def reference(name: str):
     return _module(BENCH / "references" / f"{name}.py", f"bench_ref_{name}")
+
+
+def counts(name: str):
+    return _module(BENCH / "counts" / f"{name}.py", f"bench_counts_{name}")
 
 
 @dataclasses.dataclass

@@ -18,7 +18,8 @@ def _follow(cell):
     return job_seed(SEED, job["id"]), int(job["total_steps"])
 
 
-@pytest.mark.parametrize("workload", ["olmo-1b-l4.steady", "mamba2-130m.churn"])
+@pytest.mark.parametrize("workload", ["olmo-1b-l4.steady", "mamba2-130m.churn",
+                                      "mamba2-130m.steady"])
 def test_controls_fail_the_limits(workload):
     cell = tc.tiny(workload)
     seed, total = _follow(cell)

@@ -42,6 +42,19 @@ def test_config_counts(name, per_token):
     assert flops.per_token(spec.config(name)) == per_token
 
 
+@pytest.mark.parametrize("entry", spec.benchmark()["configs"],
+                         ids=lambda c: c["name"])
+def test_every_config_has_its_count_file(entry):
+    cfg = spec.config(entry["name"])
+    count = spec.counts(cfg["flops"]).per_token
+    assert count(cfg["model"], cfg["train"]["seq_len"]) == flops.per_token(cfg)
+
+
+def test_unknown_count_raises():
+    with pytest.raises(KeyError, match="counts/no_such_count.py"):
+        spec.counts("no_such_count")
+
+
 def test_peaks_keyed_by_kind():
     assert peaks.peak("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(KeyError):
