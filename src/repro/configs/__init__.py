@@ -31,12 +31,14 @@ from repro.configs.granite_8b import CONFIG as _granite
 from repro.configs.qwen3_moe_30b_a3b import CONFIG as _qwen3_moe
 from repro.configs.mamba2_130m import CONFIG as _mamba2
 from repro.configs.paper_gpt2_1_8b import CONFIG as _paper_gpt2
+from repro.configs.nemotron_3_nano_30b_a3b import CONFIG as _nemotron3_nano
 
 _REGISTRY: Dict[str, ModelConfig] = {
     c.name: c
     for c in (
         _h2o, _zamba2, _olmo, _whisper, _yi, _llama_vis,
         _granite_moe, _granite, _qwen3_moe, _mamba2, _paper_gpt2,
+        _nemotron3_nano,
     )
 }
 

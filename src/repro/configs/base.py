@@ -8,18 +8,28 @@ cleanly and can be used as jit static arguments.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int              # the router's outputs
     top_k: int
-    # capacity factor for expert-parallel dispatch (tokens per expert buffer).
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     # shared (always-on) expert FFN width; 0 = none.
     shared_expert_ff: int = 0
+    # "softmax": top-k of the softmax, renormalized (Switch / Mixtral);
+    # "sigmoid": top-k of sigmoid scores, renormalized over the k and times
+    # ``routed_scale`` (DeepSeek-V3 / Nemotron-H)
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # experts this chip holds, from expert 0: its share when several chips
+    # share the layer; 0 = all ``num_experts``
+    num_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,9 +37,17 @@ class SSMConfig:
     """Mamba2 / SSD block configuration (arXiv:2405.21060)."""
     state_dim: int = 128          # N, SSM state size
     head_dim: int = 64            # P, channels per SSD head
-    expand: int = 2               # d_inner = expand * d_model
+    expand: int = 2               # d_inner = expand * d_model unless num_heads
     chunk_size: int = 128         # SSD chunked-scan block length
     conv_width: int = 4           # depthwise causal conv width
+    n_groups: int = 1             # G, B/C groups; head h reads group h // (H/G)
+    num_heads: int = 0            # H; 0 = expand * d_model / head_dim
+
+    def heads(self, d_model: int) -> int:
+        return self.num_heads or self.expand * d_model // self.head_dim
+
+    def d_inner(self, d_model: int) -> int:
+        return self.heads(d_model) * self.head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +69,7 @@ class VLMConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    arch_type: str                # dense | moe | ssm | hybrid | audio | vlm
+    arch_type: str                # dense | moe | ssm | hybrid | audio | vlm | pattern
     num_layers: int
     d_model: int
     num_heads: int                # query heads (0 for attention-free)
@@ -64,12 +82,16 @@ class ModelConfig:
     rope_theta: float = 10000.0
     # normalization: "rmsnorm" | "nonparametric_ln" (olmo) | "layernorm"
     norm: str = "rmsnorm"
-    # mlp: "swiglu" | "gelu"
+    norm_eps: float = 1e-6        # RMSNorm epsilon
+    # mlp: "swiglu" | "gelu" | "relu2"
     mlp: str = "swiglu"
     tie_embeddings: bool = False
     # hybrid: attention block every k layers (zamba2-style shared block); 0 = n/a
     attn_every: int = 0
     shared_attn_block: bool = False
+    # pattern: one mixer per block, in order: "M" Mamba-2, "E" MoE,
+    # "*" attention, "-" dense MLP (Nemotron-H's hybrid_override_pattern)
+    layer_pattern: str = ""
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     encdec: Optional[EncDecConfig] = None
@@ -88,9 +110,36 @@ class ModelConfig:
     def is_attention_free(self) -> bool:
         return self.arch_type == "ssm"
 
+    def pattern_block_params(self, experts: int = 0) -> Dict[str, int]:
+        """Parameters of one block of each ``layer_pattern`` letter, its
+        pre-norm included; an "E" block with ``experts`` experts (0: the
+        held ones)."""
+        d = self.d_model
+        mlp_mats = 3 if self.mlp == "swiglu" else 2
+        out = {"-": d + mlp_mats * d * self.d_ff}
+        if self.num_heads:
+            hd = self.resolved_head_dim()
+            out["*"] = d + 2 * d * hd * (self.num_heads + self.num_kv_heads)
+        if self.ssm is not None:
+            s = self.ssm
+            d_in, h = s.d_inner(d), s.heads(d)
+            conv_ch = d_in + 2 * s.n_groups * s.state_dim
+            out["M"] = (d + d * (d_in + conv_ch + h) + (s.conv_width + 1) * conv_ch
+                        + 3 * h + d_in + d_in * d)
+        if self.moe is not None:
+            m = self.moe
+            shared = mlp_mats * d * m.shared_expert_ff
+            out["E"] = (d + d * m.num_experts + shared
+                        + (experts or m.held) * mlp_mats * d * self.d_ff)
+        return out
+
     def param_count(self) -> int:
         """Analytic parameter count (embeddings + blocks + head)."""
         d, v = self.d_model, self.vocab_size
+        if self.arch_type == "pattern":
+            per = self.pattern_block_params()
+            head = 0 if self.tie_embeddings else v * d
+            return v * d + head + d + sum(per[c] for c in self.layer_pattern)
         total = v * d                      # embed
         if not self.tie_embeddings:
             total += v * d                 # lm head
@@ -143,7 +192,15 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top-k of experts)."""
+        """Parameters touched per token (MoE: top-k of experts).  A
+        ``pattern`` model leaves out the input embedding, as its published
+        active count does."""
+        if self.arch_type == "pattern":
+            per = self.pattern_block_params()
+            idle = per["E"] - self.pattern_block_params(self.moe.top_k)["E"] \
+                if self.moe is not None else 0
+            return (self.param_count() - self.vocab_size * self.d_model
+                    - idle * self.layer_pattern.count("E"))
         if self.arch_type != "moe":
             return self.param_count()
         m = self.moe
@@ -221,7 +278,11 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 256,
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(
             cfg.ssm, state_dim=min(cfg.ssm.state_dim, 16), head_dim=32,
-            chunk_size=32)
+            chunk_size=32, num_heads=0)
+    if cfg.layer_pattern:
+        # one block of each kind, in the order they first appear
+        kinds = "".join(dict.fromkeys(cfg.layer_pattern))
+        changes.update(layer_pattern=kinds, num_layers=len(kinds))
     if cfg.encdec is not None:
         changes["encdec"] = dataclasses.replace(
             cfg.encdec, encoder_layers=2, encoder_seq=64)

@@ -21,6 +21,10 @@ Spans on the runtime's ``profiler`` (``repro.utils.profiler``):
   compile or cache load, and enqueue.  ``<cause>`` says why the runtime
   needed the program: ``admit`` (a fresh runtime), ``restore``
   (``from_snapshot``) or ``resize``.
+- counters ``moe.rows``, ``moe.rows_peak``, ``moe.block_steps``, of a
+  model with MoE blocks, read in ``step.wait`` once the step has run: the
+  rows its held experts computed; per block and step the busiest held
+  expert's rows, summed; and the blocks times the steps.
 - ``ckpt.d2h`` copies the state to the host for a snapshot;
   ``restore.h2d`` copies a snapshot's state to the device, and waits for
   the copy only when the profiler is enabled.
@@ -122,6 +126,8 @@ class ElasticRuntime:
             with self.prof.span("step.wait"):
                 acquired = self.barrier.observe(metrics["barrier"])
                 step, loss = int(self.state["step"]), float(metrics["loss"])
+                if "moe_rows" in metrics:
+                    self._count_rows(np.asarray(metrics["moe_rows"]))
             rec = {"step": step,
                    "loss": loss,
                    "splice": self.splice,
@@ -132,6 +138,12 @@ class ElasticRuntime:
             if acquired and stop_on_barrier:
                 break
         return out
+
+    def _count_rows(self, rows: np.ndarray) -> None:
+        """rows: (MoE blocks, held experts) of one step."""
+        self.prof.add("moe.rows", float(rows.sum()))
+        self.prof.add("moe.rows_peak", float(rows.max(axis=-1).sum()))
+        self.prof.add("moe.block_steps", float(rows.shape[0]))
 
     # ---------------------------------------------------------------- resize
     def resize(self, new_physical: int) -> Dict:

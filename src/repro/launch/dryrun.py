@@ -69,14 +69,9 @@ def _memory_stats(compiled) -> Optional[Dict]:
 def lower_pair(arch: str, shape_name: str, multi_pod: bool,
                splice: int = 1, remat: bool = True, donate: bool = False,
                remat_policy: str = "full", shard_profile: str = "default",
-               moe_capacity_factor: Optional[float] = None,
-               fused_gate: bool = False,
                mesh_override: Optional[tuple] = None,
                extra_tags: Optional[Dict] = None) -> Dict:
     """Lower + compile one pair on one mesh; returns the result record."""
-    import dataclasses as _dc
-
-    from repro.models import moe as _moe
     from repro.parallel import constraints as _constraints
 
     plan = plan_pair(arch, shape_name)
@@ -85,10 +80,6 @@ def lower_pair(arch: str, shape_name: str, multi_pod: bool,
         return {"arch": arch, "shape": shape_name, "mesh": mesh_name,
                 "status": "skipped", "reason": plan.skip_reason}
     cfg, shape = plan.cfg, plan.shape
-    if moe_capacity_factor is not None and cfg.moe is not None:
-        cfg = _dc.replace(cfg, moe=_dc.replace(
-            cfg.moe, capacity_factor=moe_capacity_factor))
-    _moe.FUSED_GATE = fused_gate
     _constraints.DISABLE_MODEL_CONSTRAINTS = (shard_profile == "replicate_model")
     if mesh_override is not None:
         mesh = make_mesh(mesh_override, ("data", "model"))
@@ -146,7 +137,6 @@ def lower_pair(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         compile_s = time.time() - t1
 
-    _moe.FUSED_GATE = False
     _constraints.DISABLE_MODEL_CONSTRAINTS = False
     cost = _cost_dict(compiled)
     mem = _memory_stats(compiled)

@@ -24,8 +24,7 @@ from repro.launch.dryrun import lower_pair
 
 def run_variant(arch: str, shape: str, mesh: str, variant: str) -> dict:
     kw = dict(splice=1, remat=True, donate=False, remat_policy="full",
-              shard_profile="default", moe_capacity_factor=None,
-              fused_gate=False, mesh_override=None)
+              shard_profile="default", mesh_override=None)
     for part in variant.split("+"):
         if part.startswith("splice"):
             kw["splice"] = int(part[len("splice"):])
@@ -37,10 +36,6 @@ def run_variant(arch: str, shape: str, mesh: str, variant: str) -> dict:
             kw["remat_policy"] = "dots"
         elif part == "nomodeltp":
             kw["shard_profile"] = "replicate_model"
-        elif part.startswith("cf"):
-            kw["moe_capacity_factor"] = float(part[2:]) / 100.0
-        elif part == "fusedgate":
-            kw["fused_gate"] = True
         elif part.startswith("chips"):
             n = int(part[len("chips"):])
             # right-size the mesh: keep data=16 (batch sharding), shrink TP

@@ -8,6 +8,7 @@ computation for the TPU hot path; this module is also its oracle's basis.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -145,14 +146,134 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :sq]
 
 
+def _to_blocks(x: jax.Array, blk: int) -> jax.Array:
+    """(B, S, H, D) -> (S/blk, B, H, blk, D), padding S to a multiple."""
+    b, s, h, d = x.shape
+    x = jnp.pad(x, ((0, 0), (0, (-s) % blk), (0, 0), (0, 0)))
+    return x.reshape(b, -1, blk, h, d).transpose(1, 0, 3, 2, 4)
+
+
+def _from_blocks(x: jax.Array, s: int) -> jax.Array:
+    n, b, h, blk, d = x.shape
+    return x.transpose(1, 0, 3, 2, 4).reshape(b, n * blk, h, d)[:, :s]
+
+
+def _score_bias(qpos, kpos, skv: int, causal: bool) -> jax.Array:
+    mask = kpos[None, :] < skv
+    if causal:
+        mask = mask & (kpos[None, :] <= qpos[:, None])
+    return jnp.where(mask, 0.0, NEG_INF).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = True, block: int = 512) -> jax.Array:
+    """Online-softmax attention whose backward pass recomputes each block's
+    scores from the saved log-sum-exp (FlashAttention, arXiv:2205.14135)
+    instead of keeping them: memory O(S * D), not O(S^2), through
+    autodiff.  q, k, v: (B, S, H, D), kv heads already repeated; every
+    block of keys is visited, masked where causal."""
+    return _flash_fwd(q, k, v, causal, block)[0]
+
+
+def _flash_blocks(q, k, v, block):
+    """Scaled query blocks, key and value blocks, and their positions."""
+    bq, bk = min(block, q.shape[1]), min(block, k.shape[1])
+    qb = _to_blocks(q, bq) * (1.0 / math.sqrt(q.shape[-1]))
+    kb, vb = _to_blocks(k, bk), _to_blocks(v, bk)
+    q_pos = jnp.arange(qb.shape[0] * bq).reshape(-1, bq)
+    kv_pos = jnp.arange(kb.shape[0] * bk).reshape(-1, bk)
+    return qb, kb, vb, q_pos, kv_pos
+
+
+def _flash_fwd(q, k, v, causal, block):
+    s, skv = q.shape[1], k.shape[1]
+    qb, kb, vb, q_pos, kv_pos = _flash_blocks(q, k, v, block)
+
+    def q_block(_, xs):
+        qi, qpos = xs
+
+        def kv_block(acc, ys):
+            m, l, o = acc
+            kj, vj, kpos = ys
+            sc = jnp.einsum("bhqd,bhkd->bhqk", qi, kj,
+                            preferred_element_type=jnp.float32)
+            sc = sc + _score_bias(qpos, kpos, skv, causal)[None, None]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            p = jnp.exp(sc - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            o = o * corr[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return (m_new, l * corr + jnp.sum(p, axis=-1), o), None
+
+        shape = qi.shape[:-1]
+        init = (jnp.full(shape, NEG_INF, jnp.float32),
+                jnp.zeros(shape, jnp.float32),
+                jnp.zeros(qi.shape, jnp.float32))
+        (m, l, o), _ = jax.lax.scan(kv_block, init, (kb, vb, kv_pos))
+        return None, ((o / l[..., None]).astype(q.dtype), m + jnp.log(l))
+
+    _, (ob, lse) = jax.lax.scan(q_block, None, (qb, q_pos))
+    out = _from_blocks(ob, s)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, block, res, g):
+    q, k, v, out, lse = res
+    s, skv = q.shape[1], k.shape[1]
+    qb, kb, vb, q_pos, kv_pos = _flash_blocks(q, k, v, block)
+    bq = qb.shape[-2]
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    gb = _to_blocks(g, bq)
+    db = _to_blocks(delta[..., None], bq)[..., 0]
+
+    def kv_block(dq, ys):
+        kj, vj, kpos = ys
+
+        def q_block(acc, xs):
+            dk, dv = acc
+            qi, gi, li, di, qpos = xs
+            sc = jnp.einsum("bhqd,bhkd->bhqk", qi, kj,
+                            preferred_element_type=jnp.float32)
+            sc = sc + _score_bias(qpos, kpos, skv, causal)[None, None]
+            p = jnp.exp(sc - li[..., None])
+            dv = dv + jnp.einsum("bhqk,bhqd->bhkd", p.astype(gi.dtype), gi,
+                                 preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", gi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - di[..., None])
+            dk = dk + jnp.einsum("bhqk,bhqd->bhkd", ds.astype(qi.dtype), qi,
+                                 preferred_element_type=jnp.float32)
+            dqi = jnp.einsum("bhqk,bhkd->bhqd", ds.astype(kj.dtype), kj,
+                             preferred_element_type=jnp.float32)
+            return (dk, dv), dqi
+
+        zero = jnp.zeros(kj.shape, jnp.float32)
+        (dk, dv), dqs = jax.lax.scan(q_block, (zero, zero),
+                                     (qb, gb, lse, db, q_pos))
+        return dq + dqs, (dk, dv)
+
+    dq, (dkb, dvb) = jax.lax.scan(
+        kv_block, jnp.zeros(qb.shape, jnp.float32), (kb, vb, kv_pos))
+    return (_from_blocks(dq * (1.0 / math.sqrt(q.shape[-1])), s).astype(q.dtype),
+            _from_blocks(dkb, skv).astype(k.dtype),
+            _from_blocks(dvb, skv).astype(v.dtype))
+
+
+flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
 def attention_forward(params: Dict, x: jax.Array, *, num_heads: int,
                       num_kv_heads: int, rope_theta: float, window: int = 0,
                       positions: Optional[jax.Array] = None,
                       kv: Optional[jax.Array] = None,
-                      causal: bool = True) -> jax.Array:
+                      causal: bool = True, recompute: bool = False) -> jax.Array:
     """Full attention layer (projections + blockwise core).
 
     kv: optional cross-attention source (B, Skv, d_model); None = self-attn.
+    recompute: the core is ``flash_attention``, whose backward recomputes
+    the scores (long sequences: no (S, S) residuals); full windows only.
     """
     b, s, _ = x.shape
     src = x if kv is None else kv
@@ -181,7 +302,12 @@ def attention_forward(params: Dict, x: jax.Array, *, num_heads: int,
         q = constrain(jnp.pad(q, padh), BATCH, None, MODEL, None)
         k = constrain(jnp.pad(k, padh), BATCH, None, MODEL, None)
         v = constrain(jnp.pad(v, padh), BATCH, None, MODEL, None)
-    o = blockwise_attention(q, k, v, causal=causal and kv is None, window=window)
+    if recompute:
+        assert not window, "flash_attention has no window"
+        o = flash_attention(q, k, v, causal and kv is None)
+    else:
+        o = blockwise_attention(q, k, v, causal=causal and kv is None,
+                                window=window)
     if hpad:
         o = o[:, :, :nh]
     o = constrain(o, BATCH, None, MODEL, None)

@@ -32,14 +32,16 @@ def layernorm(x: jax.Array, scale: Optional[jax.Array], bias: Optional[jax.Array
     return x.astype(dtype)
 
 
-def apply_norm(kind: str, x: jax.Array, params: Optional[dict]) -> jax.Array:
-    """Dispatch on the config's norm kind.
+def apply_norm(kind: str, x: jax.Array, params: Optional[dict],
+               eps: float = 1e-6) -> jax.Array:
+    """Dispatch on the config's norm kind; ``eps`` is the RMSNorm's
+    (``ModelConfig.norm_eps``).
 
     ``nonparametric_ln`` (olmo, arXiv:2402.00838) is LayerNorm with no
     learned scale/bias — params is None.
     """
     if kind == "rmsnorm":
-        return rmsnorm(x, params["scale"] if params else None)
+        return rmsnorm(x, params["scale"] if params else None, eps)
     if kind == "layernorm":
         return layernorm(x, params["scale"] if params else None,
                          params.get("bias") if params else None)

@@ -1,4 +1,4 @@
-"""Feed-forward layers: SwiGLU and GELU MLPs."""
+"""Feed-forward layers: SwiGLU, GELU and ReLU^2 MLPs."""
 from __future__ import annotations
 
 import math
@@ -20,7 +20,7 @@ def init_mlp(key, d_model: int, d_ff: int, kind: str, dtype=jnp.float32) -> Dict
             "wg": dense_init(k2, (d_model, d_ff), dtype=dtype),
             "wo": dense_init(k3, (d_ff, d_model), scale=out_scale, dtype=dtype),
         }
-    if kind == "gelu":
+    if kind in ("gelu", "relu2"):
         return {
             "wi": dense_init(k1, (d_model, d_ff), dtype=dtype),
             "wo": dense_init(k3, (d_ff, d_model), scale=out_scale, dtype=dtype),
@@ -28,14 +28,22 @@ def init_mlp(key, d_model: int, d_ff: int, kind: str, dtype=jnp.float32) -> Dict
     raise ValueError(f"unknown mlp kind {kind!r}")
 
 
+def activation(kind: str, h: jax.Array) -> jax.Array:
+    """The non-gated MLPs' activation: GELU, or ReLU^2 (``relu(h)^2``,
+    Primer / Nemotron-H)."""
+    if kind == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    return jax.nn.gelu(h)
+
+
 def mlp_forward(params: Dict, x: jax.Array, kind: str) -> jax.Array:
     if kind == "swiglu":
         h = jnp.einsum("bsd,df->bsf", x, params["wi"].astype(x.dtype))
         g = jnp.einsum("bsd,df->bsf", x, params["wg"].astype(x.dtype))
         h = jax.nn.silu(g) * constrain(h, BATCH, None, MODEL)
-    else:  # gelu
+    else:
         h = jnp.einsum("bsd,df->bsf", x, params["wi"].astype(x.dtype))
-        h = jax.nn.gelu(constrain(h, BATCH, None, MODEL))
+        h = activation(kind, constrain(h, BATCH, None, MODEL))
     return constrain(
         jnp.einsum("bsf,fd->bsd", h, params["wo"].astype(x.dtype)),
         BATCH, None, None)

@@ -1,7 +1,7 @@
 """Generic model assembly for all assigned architecture families.
 
-One parameter/function pair covers dense, MoE, SSM, hybrid, audio (enc-dec)
-and VLM families, driven entirely by ``ModelConfig``:
+One parameter/function pair covers dense, MoE, SSM, hybrid, audio (enc-dec),
+VLM and layer-pattern families, driven entirely by ``ModelConfig``:
 
 - ``init_params``     — parameter pytree (layers stacked for lax.scan)
 - ``model_forward``   — training forward -> (loss, metrics)
@@ -14,7 +14,10 @@ per block).  Heterogeneous extras (zamba2 shared attention block, VLM
 cross-attention every k layers) use GROUP SCANS — an outer scan over
 groups of ``every`` layers with the extra block applied once per group —
 rather than ``lax.cond``, so the lowered HLO has no conditionals on the
-hot path (exact roofline accounting, cheaper compile).
+hot path (exact roofline accounting, cheaper compile).  A ``pattern`` model
+(Nemotron-H: unlike single-mixer blocks in a fixed order) walks its
+pattern in Python over parameters stacked per mixer kind; it trains, and
+does not serve.
 """
 from __future__ import annotations
 
@@ -85,6 +88,42 @@ def _stack_init(fn, key, n: int):
     return jax.vmap(fn)(keys)
 
 
+# the mixer of each ``layer_pattern`` letter, which names its blocks' stack
+PATTERN_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "-": "mlp"}
+
+
+def _init_mixer_block(cfg: ModelConfig, kind: str, key, dtype) -> Dict:
+    """A pattern model's block: its pre-norm ``ln1`` and one mixer."""
+    block = {"ln1": norm_param(cfg.norm, cfg.d_model, dtype)}
+    if kind == "mamba":
+        block["ssm"] = ssm_lib.init_ssm(key, cfg.d_model, cfg.ssm, dtype)
+    elif kind == "moe":
+        block["moe"] = moe_lib.init_moe(key, cfg.d_model, cfg.d_ff, cfg.mlp,
+                                        cfg.moe, dtype)
+    elif kind == "attn":
+        block["attn"] = attn_lib.init_attention(
+            key, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+            cfg.resolved_head_dim(), dtype)
+    else:
+        block["mlp"] = mlp_lib.init_mlp(key, cfg.d_model, cfg.d_ff, cfg.mlp,
+                                        dtype)
+    return block
+
+
+def _init_pattern(cfg: ModelConfig, key, dtype) -> Dict:
+    """Blocks stacked per mixer kind, in pattern order within a kind: few
+    and large leaves.  ``key`` splits 4 ways, one per kind in
+    ``PATTERN_KINDS`` order; a kind's key splits once per block of it."""
+    keys = jax.random.split(key, len(PATTERN_KINDS))
+    out = {}
+    for (letter, kind), k in zip(PATTERN_KINDS.items(), keys):
+        n = cfg.layer_pattern.count(letter)
+        if n:
+            out[kind] = _stack_init(functools.partial(
+                _init_mixer_block, cfg, kind, dtype=dtype), k, n)
+    return out
+
+
 def group_layout(cfg: ModelConfig) -> Tuple[int, int, int]:
     """(n_groups, layers_per_group, tail_layers) for group-scan archs."""
     if cfg.arch_type == "hybrid":
@@ -121,6 +160,9 @@ def _merge_groups(grouped, tailt):
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
+    """``key`` splits 8 ways: embedding 0.02-normal from key 0, the untied
+    head from key 1, the blocks from key 2 (``_init_pattern`` for a pattern
+    model), then the families' extras."""
     ks = jax.random.split(key, 8)
     params: Dict = {
         "embed": dense_init(ks[0], (cfg.vocab_size, cfg.d_model), dtype=dtype),
@@ -135,6 +177,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
         block_fn = functools.partial(_init_moe_block, cfg, dtype=dtype)
     elif cfg.arch_type in ("ssm", "hybrid"):
         block_fn = functools.partial(_init_ssm_block, cfg, dtype=dtype)
+    elif cfg.arch_type == "pattern":
+        assert len(cfg.layer_pattern) == cfg.num_layers, cfg.layer_pattern
+        params["blocks"] = _init_pattern(cfg, ks[2], dtype)
+        return params
     else:
         raise ValueError(cfg.arch_type)
     params["blocks"] = _stack_init(block_fn, ks[2], cfg.num_layers)
@@ -169,7 +215,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
 # ---------------------------------------------------------------------------
 
 def _self_attn(cfg: ModelConfig, block: Dict, x: jax.Array) -> jax.Array:
-    h = apply_norm(cfg.norm, x, block["ln1"])
+    h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
     h = attn_lib.attention_forward(
         block["attn"], h, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         rope_theta=cfg.rope_theta, window=cfg.sliding_window)
@@ -177,7 +223,7 @@ def _self_attn(cfg: ModelConfig, block: Dict, x: jax.Array) -> jax.Array:
 
 
 def _mlp_res(cfg: ModelConfig, block: Dict, x: jax.Array) -> jax.Array:
-    h = apply_norm(cfg.norm, x, block["ln2"])
+    h = apply_norm(cfg.norm, x, block["ln2"], cfg.norm_eps)
     return x + mlp_lib.mlp_forward(block["mlp"], h, cfg.mlp)
 
 
@@ -186,21 +232,22 @@ def _dense_block(cfg: ModelConfig, block: Dict, x: jax.Array) -> jax.Array:
 
 
 def _moe_block(cfg: ModelConfig, block: Dict, x: jax.Array
-               ) -> Tuple[jax.Array, jax.Array]:
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """-> (x, aux loss, rows routed to each held expert)."""
     x = _self_attn(cfg, block, x)
-    h = apply_norm(cfg.norm, x, block["ln2"])
-    out, aux = moe_lib.moe_forward(block["moe"], h, cfg.mlp, cfg.moe)
-    return x + out, aux
+    h = apply_norm(cfg.norm, x, block["ln2"], cfg.norm_eps)
+    out, aux, rows = moe_lib.moe_forward(block["moe"], h, cfg.mlp, cfg.moe)
+    return x + out, aux, rows
 
 
 def _ssm_block(cfg: ModelConfig, block: Dict, x: jax.Array) -> jax.Array:
-    h = apply_norm(cfg.norm, x, block["ln1"])
-    return x + ssm_lib.ssm_forward(block["ssm"], h, cfg.ssm)
+    h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
+    return x + ssm_lib.ssm_forward(block["ssm"], h, cfg.ssm, cfg.norm_eps)
 
 
 def _cross_block(cfg: ModelConfig, cblock: Dict, x: jax.Array,
                  kv_src: jax.Array) -> jax.Array:
-    h = apply_norm(cfg.norm, x, cblock["ln"])
+    h = apply_norm(cfg.norm, x, cblock["ln"], cfg.norm_eps)
     h = attn_lib.attention_forward(
         cblock["attn"], h, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         rope_theta=0.0, kv=kv_src, causal=False)
@@ -210,7 +257,7 @@ def _cross_block(cfg: ModelConfig, cblock: Dict, x: jax.Array,
 
 def _audio_block(cfg: ModelConfig, block: Dict, cross: Dict, x: jax.Array,
                  cross_src: jax.Array) -> jax.Array:
-    h = apply_norm(cfg.norm, x, block["ln1"])
+    h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
     h = attn_lib.attention_forward(
         block["attn"], h, num_heads=cfg.num_heads,
         num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta)
@@ -229,7 +276,7 @@ def _encoder_forward(cfg: ModelConfig, params: Dict, frames: jax.Array) -> jax.A
     x = frames + pe.astype(frames.dtype)
 
     def body(x, block):
-        h = apply_norm(cfg.norm, x, block["ln1"])
+        h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
         h = attn_lib.attention_forward(
             block["attn"], h, num_heads=cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads, rope_theta=0.0, causal=False)
@@ -237,7 +284,7 @@ def _encoder_forward(cfg: ModelConfig, params: Dict, frames: jax.Array) -> jax.A
         return _mlp_res(cfg, block, x), None
 
     x, _ = jax.lax.scan(body, x, enc["blocks"])
-    return apply_norm(cfg.norm, x, enc["final_norm"])
+    return apply_norm(cfg.norm, x, enc["final_norm"], cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -253,11 +300,51 @@ def _remat_wrapper(remat, policy: str = "full"):
     return jax.checkpoint
 
 
+def _pattern_block(cfg: ModelConfig, kind: str, i: int, stack: Dict,
+                   x: jax.Array):
+    """Block ``i`` of ``kind``'s stack: ``x + mixer(norm(x))``; returns
+    (x, aux loss, rows routed to each held expert or None)."""
+    block = jax.tree_util.tree_map(lambda a: a[i], stack)
+    h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
+    aux, rows = jnp.zeros((), jnp.float32), None
+    if kind == "mamba":
+        h = ssm_lib.ssm_forward(block["ssm"], h, cfg.ssm, cfg.norm_eps)
+    elif kind == "moe":
+        h, aux, rows = moe_lib.moe_forward(block["moe"], h, cfg.mlp, cfg.moe)
+    elif kind == "attn":
+        h = attn_lib.attention_forward(
+            block["attn"], h, num_heads=cfg.num_heads,
+            num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
+            recompute=True)
+    else:
+        h = mlp_lib.mlp_forward(block["mlp"], h, cfg.mlp)
+    return x + h, aux, rows
+
+
+def _pattern_blocks(cfg: ModelConfig, params: Dict, x: jax.Array, ckpt):
+    """Walk ``layer_pattern`` in Python, one remat per block; a block takes
+    its slice of its kind's stack inside the remat, so no slice of the
+    parameters outlives it."""
+    aux = jnp.zeros((), jnp.float32)
+    rows = []
+    seen = dict.fromkeys(PATTERN_KINDS.values(), 0)
+    for letter in cfg.layer_pattern:
+        kind = PATTERN_KINDS[letter]
+        block = ckpt(functools.partial(_pattern_block, cfg, kind, seen[kind]))
+        seen[kind] += 1
+        x, a, r = block(params["blocks"][kind], x)
+        aux = aux + a
+        if r is not None:
+            rows.append(r)
+    return (x, aux, jnp.stack(rows)) if rows else (x, aux)
+
+
 def _scan_blocks(cfg: ModelConfig, params: Dict, x: jax.Array,
                  cross_src: Optional[jax.Array], remat: bool = True,
-                 remat_policy: str = "full"
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Run all layers; returns (hidden, aux_loss_sum)."""
+                 remat_policy: str = "full"):
+    """Run all layers; returns (hidden, aux_loss_sum) and, where the model
+    has MoE blocks, a third item: the rows routed to each held expert per
+    MoE block, (blocks, held) int32."""
     ckpt = _remat_wrapper(remat, remat_policy)
     aux0 = jnp.zeros((), jnp.float32)
 
@@ -273,10 +360,13 @@ def _scan_blocks(cfg: ModelConfig, params: Dict, x: jax.Array,
     if cfg.arch_type == "moe":
         def layer(carry, block):
             x, aux = carry
-            x, a = _moe_block(cfg, block, x)
-            return (x, aux + a), None
-        (x, aux), _ = jax.lax.scan(ckpt(layer), (x, aux0), params["blocks"])
-        return x, aux
+            x, a, rows = _moe_block(cfg, block, x)
+            return (x, aux + a), rows
+        (x, aux), rows = jax.lax.scan(ckpt(layer), (x, aux0), params["blocks"])
+        return x, aux, rows
+
+    if cfg.arch_type == "pattern":
+        return _pattern_blocks(cfg, params, x, ckpt)
 
     if cfg.arch_type == "audio":
         def layer(carry, inp):
@@ -390,14 +480,17 @@ def model_forward(params: Dict, batch: Dict, cfg: ModelConfig,
         cross_src = _encoder_forward(
             cfg, params, batch["encoder_frames"].astype(compute_dtype))
 
-    x, aux = _scan_blocks(cfg, params, x, cross_src, remat=remat,
-                          remat_policy=remat_policy)
-    x = apply_norm(cfg.norm, x, params["final_norm"])
+    x, aux, *rows = _scan_blocks(cfg, params, x, cross_src, remat=remat,
+                                 remat_policy=remat_policy)
+    x = apply_norm(cfg.norm, x, params["final_norm"], cfg.norm_eps)
     loss_sum, count = chunked_cross_entropy(x, _lm_head(cfg, params),
                                             batch["labels"])
     ce = loss_sum / jnp.maximum(count, 1.0)
     loss = ce + aux
-    return loss, {"ce": ce, "aux": aux, "tokens": count}
+    metrics = {"ce": ce, "aux": aux, "tokens": count}
+    if rows:
+        metrics["moe_rows"] = rows[0]
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------
@@ -411,8 +504,14 @@ def cache_length(cfg: ModelConfig, seq_len: int) -> int:
     return seq_len
 
 
+def _no_serving(cfg: ModelConfig) -> None:
+    if cfg.arch_type == "pattern":
+        raise NotImplementedError("prefill and decode of a pattern model")
+
+
 def init_decode_state(cfg: ModelConfig, batch: int, seq_len: int,
                       dtype=jnp.bfloat16) -> Dict:
+    _no_serving(cfg)
     hd = cfg.resolved_head_dim() if cfg.num_heads else 0
     state: Dict = {"pos": jnp.zeros((), jnp.int32)}
     clen = cache_length(cfg, seq_len)
@@ -453,6 +552,7 @@ def init_decode_state(cfg: ModelConfig, batch: int, seq_len: int,
 def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
                    cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     """One decode step.  token: (B,) int32.  Returns (logits (B,V), state)."""
+    _no_serving(cfg)
     compute_dtype = jnp.dtype(cfg.dtype)
     pos = state["pos"]
     x = params["embed"].astype(compute_dtype)[token][:, None]  # (B,1,d)
@@ -460,7 +560,7 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
     window = cfg.sliding_window
 
     def attn_decode(block, x, cache):
-        h = apply_norm(cfg.norm, x, block["ln1"])
+        h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
         h, cache = attn_lib.decode_attention(
             block["attn"], h, cache, pos, num_heads=cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
@@ -468,7 +568,7 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
         return x + h, cache
 
     def cross_decode(cblock, ckv, x):
-        h = apply_norm(cfg.norm, x, cblock["ln"])
+        h = apply_norm(cfg.norm, x, cblock["ln"], cfg.norm_eps)
         h = attn_lib.decode_cross_attention(
             cblock["attn"], h,
             jax.tree_util.tree_map(lambda a: a.astype(compute_dtype), ckv),
@@ -485,8 +585,8 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
                 block, cache = inp
             x, cache = attn_decode(block, x, cache)
             if cfg.arch_type == "moe":
-                h = apply_norm(cfg.norm, x, block["ln2"])
-                out, _ = moe_lib.moe_forward(block["moe"], h, cfg.mlp, cfg.moe)
+                h = apply_norm(cfg.norm, x, block["ln2"], cfg.norm_eps)
+                out, _, _ = moe_lib.moe_forward(block["moe"], h, cfg.mlp, cfg.moe)
                 x = x + out
             elif cfg.arch_type == "audio":
                 x = cross_decode(cross, ckv, x)
@@ -527,7 +627,7 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
     elif cfg.arch_type == "ssm":
         def layer(x, inp):
             block, sstate = inp
-            h = apply_norm(cfg.norm, x, block["ln1"])
+            h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
             h, sstate = ssm_lib.ssm_decode_step(block["ssm"], h, sstate, cfg.ssm)
             return x + h, sstate
         x, new_ssm = jax.lax.scan(layer, x, (params["blocks"], state["ssm"]))
@@ -543,7 +643,7 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
 
         def ssm_layer(x, inp):
             block, sstate = inp
-            h = apply_norm(cfg.norm, x, block["ln1"])
+            h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
             h, sstate = ssm_lib.ssm_decode_step(block["ssm"], h, sstate, cfg.ssm)
             return x + h, sstate
 
@@ -565,7 +665,7 @@ def decode_step_fn(params: Dict, state: Dict, token: jax.Array,
     else:
         raise ValueError(cfg.arch_type)
 
-    x = apply_norm(cfg.norm, x, params["final_norm"])
+    x = apply_norm(cfg.norm, x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x,
                         _lm_head(cfg, params).astype(compute_dtype),
                         preferred_element_type=jnp.float32)[:, 0]
@@ -607,7 +707,7 @@ def _ssm_prefill_layer(cfg: ModelConfig, block: Dict, x: jax.Array):
     d_in = sp.expand * cfg.d_model
     nheads = d_in // sp.head_dim
     nst = sp.state_dim
-    h = apply_norm(cfg.norm, x, block["ln1"])
+    h = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
     proj = jnp.einsum("bld,de->ble", h, block["ssm"]["in_proj"].astype(h.dtype))
     z = proj[..., :d_in]
     xbc = proj[..., d_in:2 * d_in + 2 * nst]
@@ -626,7 +726,7 @@ def _ssm_prefill_layer(cfg: ModelConfig, block: Dict, x: jax.Array):
     y = y + block["ssm"]["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(b, s, d_in).astype(h.dtype)
     y = y * jax.nn.silu(z)
-    y = rmsnorm(y, block["ssm"]["norm_scale"])
+    y = rmsnorm(y, block["ssm"]["norm_scale"], cfg.norm_eps)
     x = x + jnp.einsum("ble,ed->bld", y, block["ssm"]["out_proj"].astype(h.dtype))
     sstate = {"conv": xp[:, s:], "ssm": fstate}
     return x, sstate
@@ -642,6 +742,7 @@ def prefill_fn(params: Dict, batch: Dict, cfg: ModelConfig,
     default = prompt length (the dry-run convention where decode positions
     stay within seq_len).
     """
+    _no_serving(cfg)
     tokens = batch["tokens"]
     b, s = tokens.shape
     target_len = cache_len if cache_len is not None else s
@@ -673,10 +774,10 @@ def prefill_fn(params: Dict, batch: Dict, cfg: ModelConfig,
     if cfg.arch_type in ("dense", "moe", "audio"):
         def layer(x, inp):
             block = inp[0] if isinstance(inp, tuple) else inp
-            hn = apply_norm(cfg.norm, x, block["ln1"])
+            hn = apply_norm(cfg.norm, x, block["ln1"], cfg.norm_eps)
             kc, vc = fill(block, hn)
             if cfg.arch_type == "moe":
-                x, _ = _moe_block(cfg, block, x)
+                x, _, _ = _moe_block(cfg, block, x)
             elif cfg.arch_type == "audio":
                 x = _audio_block(cfg, block, inp[1], x, cross_src)
             else:
@@ -699,7 +800,7 @@ def prefill_fn(params: Dict, batch: Dict, cfg: ModelConfig,
         def group(x, inp):
             gblocks, cross = inp
             def inner(c, blk):
-                hn = apply_norm(cfg.norm, c, blk["ln1"])
+                hn = apply_norm(cfg.norm, c, blk["ln1"], cfg.norm_eps)
                 kc, vc = fill(blk, hn)
                 return _dense_block(cfg, blk, c), {"k": kc, "v": vc}
             x, gkv = jax.lax.scan(inner, x, gblocks)
@@ -728,7 +829,7 @@ def prefill_fn(params: Dict, batch: Dict, cfg: ModelConfig,
             def inner(c, blk):
                 return _ssm_prefill_layer(cfg, blk, c)
             x, gssm = jax.lax.scan(inner, x, gblocks)
-            hn = apply_norm(cfg.norm, x, sa["ln1"])
+            hn = apply_norm(cfg.norm, x, sa["ln1"], cfg.norm_eps)
             kc, vc = fill(sa, hn)
             x = _mlp_res(cfg, sa, _self_attn(cfg, sa, x))
             return x, (gssm, {"k": kc, "v": vc})
@@ -747,7 +848,7 @@ def prefill_fn(params: Dict, batch: Dict, cfg: ModelConfig,
     else:
         raise ValueError(cfg.arch_type)
 
-    x = apply_norm(cfg.norm, x, params["final_norm"])
+    x = apply_norm(cfg.norm, x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, -1],
                         _lm_head(cfg, params).astype(compute_dtype),
                         preferred_element_type=jnp.float32)

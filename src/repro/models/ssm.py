@@ -20,13 +20,12 @@ from repro.parallel.constraints import BATCH, MODEL, constrain
 
 
 def init_ssm(key, d_model: int, cfg: SSMConfig, dtype=jnp.float32) -> Dict:
-    d_in = cfg.expand * d_model
-    nheads = d_in // cfg.head_dim
-    n = cfg.state_dim
+    d_in, nheads = cfg.d_inner(d_model), cfg.heads(d_model)
+    n = cfg.n_groups * cfg.state_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     conv_ch = d_in + 2 * n
     return {
-        # in_proj -> [z (d_in), x (d_in), B (N), C (N), dt (H)]
+        # in_proj -> [z (d_in), x (d_in), B (G*N), C (G*N), dt (H)]
         "in_proj": dense_init(k1, (d_model, 2 * d_in + 2 * n + nheads), dtype=dtype),
         "conv_w": dense_init(k2, (cfg.conv_width, conv_ch), scale=0.1, dtype=dtype),
         "conv_b": jnp.zeros((conv_ch,), dtype),
@@ -47,10 +46,31 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     x:  (B, L, H, P)   inputs per head
     dt: (B, L, H)      positive step sizes (already softplus'd)
     a:  (H,)           negative decay rates (A = -exp(A_log))
-    b:  (B, L, N)      input projection (single group, broadcast over heads)
-    c:  (B, L, N)      output projection
+    b:  (B, L, G, N)   input projection of G groups; head h reads group
+                       h // (H / G).  (B, L, N) is one group, broadcast
+                       over the heads
+    c:  (B, L, G, N)   output projection, grouped as b
     Returns (y (B, L, H, P), final_state (B, H, P, N)).
+
+    G groups run as G independent scans over their H / G heads (``vmap``),
+    so ``C B^T`` is formed once per group.
     """
+    if b.ndim == 4:
+        g = b.shape[2]
+        if g == 1:
+            return ssd_chunked(x, dt, a, b[:, :, 0], c[:, :, 0], chunk,
+                               initial_state)
+        bs, l, h, p = x.shape
+        r = h // g
+        s0 = None if initial_state is None else \
+            initial_state.reshape(bs, g, r, p, -1)
+        y, final = jax.vmap(
+            lambda xg, dg, ag, bg, cg, sg: ssd_chunked(xg, dg, ag, bg, cg,
+                                                       chunk, sg),
+            in_axes=(2, 2, 0, 2, 2, None if s0 is None else 1),
+            out_axes=(2, 1))(x.reshape(bs, l, g, r, p), dt.reshape(bs, l, g, r),
+                             a.reshape(g, r), b, c, s0)
+        return y.reshape(bs, l, h, p), final.reshape(bs, h, p, -1)
     bs, l, h, p = x.shape
     n = b.shape[-1]
     pad = (-l) % chunk
@@ -118,12 +138,27 @@ def _split_proj(proj: jax.Array, d_in: int, n: int, nheads: int):
     return z, xbc, dt
 
 
-def ssm_forward(params: Dict, xin: jax.Array, cfg: SSMConfig) -> jax.Array:
-    """Full Mamba2 block: in_proj -> conv -> SSD -> gated norm -> out_proj."""
+def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, groups: int,
+               eps: float = 1e-6) -> jax.Array:
+    """``y * silu(z)``, then an RMSNorm over each of ``groups`` equal
+    slices of the channels (Zamba2 / Nemotron-H's grouped gated norm)."""
+    y = y * jax.nn.silu(z)
+    if groups == 1:
+        return rmsnorm(y, scale, eps)
+    shape = y.shape
+    y = rmsnorm(y.reshape(shape[:-1] + (groups, -1)),
+                scale.reshape(groups, -1), eps)
+    return y.reshape(shape)
+
+
+def ssm_forward(params: Dict, xin: jax.Array, cfg: SSMConfig,
+                eps: float = 1e-6) -> jax.Array:
+    """Full Mamba2 block: in_proj -> conv -> SSD -> gated norm -> out_proj.
+    ``eps`` is the gated norm's."""
     bsz, l, d_model = xin.shape
-    d_in = cfg.expand * d_model
-    nheads = d_in // cfg.head_dim
-    n = cfg.state_dim
+    d_in, nheads = cfg.d_inner(d_model), cfg.heads(d_model)
+    g = cfg.n_groups
+    n = g * cfg.state_dim
 
     proj = jnp.einsum("bld,de->ble", xin, params["in_proj"].astype(xin.dtype))
     z, xbc, dt = _split_proj(proj, d_in, n, nheads)
@@ -139,6 +174,9 @@ def ssm_forward(params: Dict, xin: jax.Array, cfg: SSMConfig) -> jax.Array:
                    BATCH, None, MODEL, None)
     bmat = conv[..., d_in:d_in + n]
     cmat = conv[..., d_in + n:]
+    if g > 1:
+        bmat = bmat.reshape(bsz, l, g, cfg.state_dim)
+        cmat = cmat.reshape(bsz, l, g, cfg.state_dim)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     a = -jnp.exp(params["A_log"])
@@ -146,8 +184,7 @@ def ssm_forward(params: Dict, xin: jax.Array, cfg: SSMConfig) -> jax.Array:
     y = y + params["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, l, d_in).astype(xin.dtype)
 
-    y = y * jax.nn.silu(z)
-    y = rmsnorm(y, params["norm_scale"])
+    y = gated_norm(y, z, params["norm_scale"], g, eps)
     return jnp.einsum("ble,ed->bld", y, params["out_proj"].astype(xin.dtype))
 
 

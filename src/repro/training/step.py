@@ -60,23 +60,25 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, splice: int = 1,
 
         def slice_body(carry, mb):
             gacc, lacc = carry
-            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, fwd), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, mb)
             gacc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32), gacc, grads)
-            return (gacc, lacc + loss), None
+            return (gacc, lacc + loss), fwd.get("moe_rows")
 
         if splice == 1:
             mb = jax.tree_util.tree_map(lambda a: a[0], mbs)
-            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, fwd), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, mb)
             grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
                                            grads)
-            lsum = loss
+            lsum, rows = loss, fwd.get("moe_rows")
         else:
-            (grads, lsum), _ = jax.lax.scan(
+            (grads, lsum), rows = jax.lax.scan(
                 slice_body, (grad_zero, jnp.zeros((), jnp.float32)), mbs)
             grads = jax.tree_util.tree_map(lambda g: g / splice, grads)
+            if rows is not None:
+                rows = jnp.sum(rows, axis=0)
 
         lr = lr_schedule(state["step"], tcfg)
         new_params, new_opt = adamw_update(params, grads, state["opt"], lr, tcfg)
@@ -87,6 +89,9 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, splice: int = 1,
             "lr": lr,
             "grad_norm": global_norm(grads),
         }
+        if rows is not None:
+            # rows routed to each held expert per MoE block, all slices
+            metrics["moe_rows"] = rows
         if with_barrier:
             # lazy import: core/__init__ imports elastic -> this module
             from repro.core.barrier_jax import meta_allreduce

@@ -67,6 +67,35 @@ print(json.dumps(out))
 """
 
 
+# the expert layer on a (2, 2) mesh: each model shard holds half of the
+# held experts (3 held, padded to 4) and the shards' parts sum to the
+# one-device forward, routing counts included
+MOE_MESH = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import dataclasses, json
+import jax
+import numpy as np
+from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
+from repro.models import init_params, model_forward
+out = {}
+for arch, held in [("granite-moe-3b-a800m", 0), ("nemotron-3-nano-30b-a3b", 3)]:
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, num_held=held))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tok = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
+    batch = {"tokens": tok, "labels": tok}
+    one = jax.jit(lambda p, b: model_forward(p, b, cfg))(params, batch)
+    with make_mesh((2, 2), ("data", "model")):
+        two = jax.jit(lambda p, b: model_forward(p, b, cfg))(params, batch)
+    out[arch] = [[float(one[0]), float(two[0])],
+                 [np.asarray(one[1]["moe_rows"]).tolist(),
+                  np.asarray(two[1]["moe_rows"]).tolist()]]
+print(json.dumps(out))
+"""
+
+
 def _run_child(script: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
@@ -90,3 +119,10 @@ def test_chip_smoke_four_chip_path():
     the psum, and its losses match the one-device reference."""
     out = _run_child(CHIP_SMOKE_4)
     assert out["payloads"] == [[0, 0], [2, 0], [2, 2]]
+
+
+def test_expert_shards_on_a_mesh_match_one_device():
+    out = _run_child(MOE_MESH)
+    for (loss_one, loss_mesh), (rows_one, rows_mesh) in out.values():
+        assert abs(loss_one - loss_mesh) <= 1e-5 * abs(loss_one), out
+        assert rows_one == rows_mesh, out
