@@ -19,7 +19,9 @@ A device leaf's blob is the one ``np.save`` writes: the npy header, then
 the data.  The store never builds that blob: it hashes each 1 MiB chunk of
 it straight from the leaf's own buffer (one contiguous copy only where the
 leaf's memory is not already ``np.save``'s data order), on a thread pool,
-and copies only the chunks new to the store.
+and copies only the chunks new to the store.  A restore builds each
+distinct device blob once, straight from its chunks into one array, on
+the same pool; workers whose blobs are the same share it.
 """
 from __future__ import annotations
 
@@ -41,10 +43,7 @@ from repro.utils.profiler import Profiler
 CHUNK = 1 << 20     # 1 MiB content chunks (page-dedup granularity)
 HASH_THREADS = 8    # cap on the store's pool (blake2b and numpy copies
                     # release the GIL)
-
-
-def _leaf_from_bytes(b: bytes):
-    return np.load(io.BytesIO(b), allow_pickle=False)
+_HEAD_MAX = 1 << 16  # bytes that hold any npy header np.load accepts
 
 
 @dataclasses.dataclass
@@ -68,8 +67,11 @@ class CheckpointStore:
     counters ``ckpt.bytes``, ``ckpt.bytes_new`` and ``ckpt.bytes_inplace``
     (device bytes of the npy blobs; of those, new to the store; of those,
     hashed straight from a leaf's own buffer).  ``restore.get`` (a whole
-    restore) holding one ``restore.read`` per worker's device copy
-    rebuilt."""
+    restore) holding one ``restore.read`` per worker: the builds of that
+    worker's device leaves not already built in this restore; counters
+    ``restore.bytes`` and ``restore.bytes_built`` (device bytes of the npy
+    blobs the manifest names, over all workers; of those, copied into a
+    newly built array)."""
 
     def __init__(self, root: Optional[str] = None,
                  profiler: Optional[Profiler] = None):
@@ -175,6 +177,31 @@ class CheckpointStore:
     def _get_blob(self, refs: List[str]) -> bytes:
         return b"".join(self._get_chunk(c) for c in refs)
 
+    def _build_leaf(self, refs: List[str]) -> Tuple[np.ndarray, int]:
+        """The array ``np.load`` gives for the npy blob of chunks ``refs``,
+        built with one copy of each chunk, on the pool.  Returns (array,
+        blob bytes)."""
+        chunks = [self._get_chunk(cs) for cs in refs]
+        head = io.BytesIO(chunks[0][:_HEAD_MAX])
+        read_header = {(1, 0): npy_format.read_array_header_1_0,
+                       (2, 0): npy_format.read_array_header_2_0}
+        shape, fortran, dtype = read_header[npy_format.read_magic(head)](head)
+        n_head = head.tell()
+        # _npy_parts writes headers far shorter than a chunk
+        assert n_head <= len(chunks[0])
+        arr = np.empty(shape, dtype, order="F" if fortran else "C")
+        flat = (arr.T if fortran else arr).reshape(-1).view(np.uint8)
+        size = sum(len(c) for c in chunks)
+        assert size == n_head + flat.nbytes, (size, n_head, flat.nbytes)
+
+        def copy(k):
+            piece = np.frombuffer(chunks[k], np.uint8)[n_head if k == 0 else 0:]
+            at = max(k * CHUNK - n_head, 0)
+            flat[at:at + len(piece)] = piece
+
+        self._map(copy, range(len(chunks)))
+        return arr, size
+
     # -------------------------------------------------------------- snapshot
     def snapshot(self, job_id: str, step: int,
                  device_state_by_worker: Dict[int, Any],
@@ -245,19 +272,36 @@ class CheckpointStore:
     # --------------------------------------------------------------- restore
     def restore(self, job_id: str, step: Optional[int] = None
                 ) -> Tuple[Dict[int, Any], Dict[int, Dict], int]:
-        """Returns (device_state_by_worker, host_state_by_worker, step)."""
-        with self.prof.span("restore.get"):
+        """Returns (device_state_by_worker, host_state_by_worker, step).
+
+        A device blob (the same chunk refs, so the same bytes) is built
+        once per restore: every leaf that names it, in any worker, is that
+        one array, so a write through one worker's tree shows in the
+        others'."""
+        prof = self.prof
+        with prof.span("restore.get"):
             snaps = self.manifests[job_id]
             manifest = snaps[-1] if step is None else \
                 next(m for m in snaps if m["step"] == step)
             device, host = {}, {}
+            built: Dict[Tuple[str, ...], Tuple[np.ndarray, int]] = {}
+            total = fresh = 0
             for w, entry in manifest["workers"].items():
                 treedef = pickle.loads(bytes.fromhex(entry["treedef"]))
-                with self.prof.span("restore.read"):
-                    leaves = [_leaf_from_bytes(self._get_blob(refs))
-                              for refs in entry["device"]]
+                leaves = []
+                with prof.span("restore.read"):
+                    for refs in entry["device"]:
+                        key = tuple(refs)
+                        if key not in built:
+                            built[key] = self._build_leaf(refs)
+                            fresh += built[key][1]
+                        leaf, size = built[key]
+                        total += size
+                        leaves.append(leaf)
                 device[int(w)] = jax.tree_util.tree_unflatten(treedef, leaves)
                 host[int(w)] = pickle.loads(self._get_blob(entry["host"]))
+            prof.add("restore.bytes", total)
+            prof.add("restore.bytes_built", fresh)
             return device, host, manifest["step"]
 
     # ----------------------------------------------------------------- sizes

@@ -2,6 +2,8 @@
 a preempt, a restore at splice 2 and a resize to splice 1 leave the
 spans and counters they should, nested as they run, and timing them
 changes no number the jobs compute."""
+import io
+
 import jax
 import numpy as np
 import pytest
@@ -67,6 +69,12 @@ def runs():
     return traced, untraced
 
 
+def _npy_size(arr) -> int:
+    buf = io.BytesIO()
+    np.save(buf, arr, allow_pickle=False)
+    return len(buf.getvalue())
+
+
 def test_spans_and_counters_of_a_churn_cycle(runs):
     (ex, ann), _ = runs
     p = ex.prof
@@ -81,6 +89,15 @@ def test_spans_and_counters_of_a_churn_cycle(runs):
     assert p.counters["ckpt.bytes_inplace"] == p.counters["ckpt.bytes"]
     assert p.counts["restore.get"] == p.counts["restore.h2d"] == 1
     assert p.counts["restore.read"] == WORLD
+    # the workers' blobs are the same: one worker's copy is built, the
+    # others reuse it; so does the optimizer's count, equal to the step
+    blobs = {tuple(refs) for worker in ex.store.manifests["basic"][-1][
+        "workers"].values() for refs in worker["device"]}
+    distinct = sum(len(ex.store._get_chunk(c)) for refs in blobs for c in refs)
+    assert p.counters["restore.bytes"] == p.counters["ckpt.bytes"]
+    assert p.counters["restore.bytes_built"] == distinct
+    assert p.counters["restore.bytes"] / WORLD - distinct == _npy_size(
+        np.int32(0))
     assert p.counts["step.build.restore"] == p.counts["step.build.resize"] == 1
     assert p.counts["step.build.admit"] == 3          # basic, prem-a, prem-b
     steps = sum(len(j.history) for j in ex.jobs.values())
